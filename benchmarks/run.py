@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
+
 # --smoke shrinks the serving trace so the CI bench step stays ~1 min
 SMOKE = False
 ROWS: list = []
@@ -750,80 +752,71 @@ def bench_serving():
 def bench_dist():
     """Sharded (butterfly-TSQR) vs single-device COALA calibration.
 
-    Runs in a subprocess with 8 fake host devices (the device count is
-    locked at jax init, which already happened in this process). On the CPU
-    container the per-shard capture loop is serialized on one host, so the
-    sharded wall time is an upper bound — on a real mesh phase 1 runs
-    per-host in parallel and only the butterfly reduce is on the wire. The
-    parity row is the claim that matters: the distributed reduction changes
-    the numbers by fp32 roundoff only. Row schema in docs/benchmarks.md.
+    Runs in this process, over a ``data`` mesh of the devices it already
+    holds (a second process could not reach an accelerator this one has
+    claimed): up to 8 shards, a power of two. On CPU give the process
+    fake devices before it starts
+    (``XLA_FLAGS=--xla_force_host_platform_device_count=8``); there one
+    host's cores compute every shard, so the sharded wall time is an
+    upper bound. The parity row is the claim that matters: the distributed
+    reduction changes the numbers by fp32 roundoff only. Row schema in
+    docs/benchmarks.md.
     """
-    import os
-    import subprocess
-    import sys
-    n_batches = 2 if SMOKE else 4
-    code = f"""
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from repro.configs import get_smoke_config
-from repro.core.calibrate import calibrate_model
-from repro.data import DataConfig, TokenPipeline
-from repro.dist.calibrate import calibrate_sharded
-cfg = get_smoke_config("smollm_135m")
-from repro.models import build_model
-model = build_model(cfg)
-params = model.init(jax.random.PRNGKey(0))
-pipe = TokenPipeline(DataConfig(vocab_size=cfg.vocab_size, seq_len=64,
-                                global_batch=8, seed=5), cfg)
-batches = [pipe.get_batch(i) for i in range({n_batches})]
-tokens = sum(int(b["tokens"].size) for b in batches)
-t0 = time.perf_counter(); single = calibrate_model(model, params, batches)
-t_single = time.perf_counter() - t0
-mesh = jax.make_mesh((8,), ("data",))
-t0 = time.perf_counter()
-sharded = calibrate_sharded(model, params, batches, mesh)
-t_sharded = time.perf_counter() - t0
-rs, rd = single.r_factors(), sharded.r_factors()
-gram_rel = max(
-    float(np.linalg.norm(np.asarray(rd[p]).T @ np.asarray(rd[p])
-                         - np.asarray(rs[p]).T @ np.asarray(rs[p]))
-          / np.linalg.norm(np.asarray(rs[p]).T @ np.asarray(rs[p])))
-    for p in rs)
-print("BENCH_JSON " + json.dumps(dict(
-    tokens=tokens, t_single=t_single, t_sharded=t_sharded,
-    gram_rel=gram_rel, layers=len(rs))))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep * bool(env.get("PYTHONPATH")) + \
-        env.get("PYTHONPATH", "")
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=900)
-    if out.returncode != 0:
-        # fail the CI bench step loudly — a quiet error row would keep the
-        # step green with the acceptance row silently missing
+    from repro.configs import get_smoke_config
+    from repro.core.calibrate import calibrate_model
+    from repro.data import DataConfig, TokenPipeline
+    from repro.dist.calibrate import calibrate_sharded
+    from repro.models import build_model
+    n = 1
+    while n * 2 <= min(len(jax.devices()), 8):
+        n *= 2
+    if n < 2:
         raise RuntimeError(
-            f"dist benchmark subprocess failed:\n{out.stderr[-2000:]}")
-    payload = json.loads(out.stdout.split("BENCH_JSON ", 1)[1])
-    tok = payload["tokens"]
-    _row("dist/calib_layers", payload["layers"], "captured linear layers")
-    _row("dist/calib_single_tok_per_s", f"{tok / payload['t_single']:.1f}",
+            "dist benchmark needs >= 2 devices (on CPU: XLA_FLAGS="
+            "--xla_force_host_platform_device_count=8)")
+    n_batches = 2 if SMOKE else 4
+    cfg = get_smoke_config("smollm_135m")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    pipe = TokenPipeline(DataConfig(vocab_size=cfg.vocab_size, seq_len=64,
+                                    global_batch=8, seed=5), cfg)
+    batches = [pipe.get_batch(i) for i in range(n_batches)]
+    tokens = sum(int(b["tokens"].size) for b in batches)
+
+    def timed(fn):
+        t0 = time.perf_counter()
+        out = fn()
+        jax.block_until_ready(out.r_factors())
+        return out, time.perf_counter() - t0
+
+    single, t_single = timed(lambda: calibrate_model(model, params, batches))
+    mesh = jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
+    sharded, t_sharded = timed(
+        lambda: calibrate_sharded(model, params, batches, mesh))
+    rs, rd = single.r_factors(), sharded.r_factors()
+    gram_rel = max(
+        float(np.linalg.norm(np.asarray(rd[p]).T @ np.asarray(rd[p])
+                             - np.asarray(rs[p]).T @ np.asarray(rs[p]))
+              / np.linalg.norm(np.asarray(rs[p]).T @ np.asarray(rs[p])))
+        for p in rs)
+    _row("dist/calib_layers", len(rs), "captured linear layers")
+    _row("dist/calib_single_tok_per_s", f"{tokens / t_single:.1f}",
          "single-device Calibrator (streaming TSQR)")
-    _row("dist/calib_sharded8_tok_per_s", f"{tok / payload['t_sharded']:.1f}",
-         "8 data shards + butterfly reduce (CPU: shard loop serialized)")
+    _row("dist/calib_shards", n, "data shards of the sharded run")
+    _row("dist/calib_sharded_tok_per_s", f"{tokens / t_sharded:.1f}",
+         f"{n} data shards + butterfly reduce "
+         f"({jax.devices()[0].platform})")
     _row("dist/sharded_vs_single_ratio",
-         f"{payload['t_single'] / payload['t_sharded']:.3f}",
+         f"{t_single / t_sharded:.3f}",
          "wall-time ratio; >1 means sharded faster (expect ~1/shards on "
          "CPU, ~shards on a real mesh)")
-    _row("dist/r_gram_rel_err", f"{payload['gram_rel']:.2e}",
+    _row("dist/r_gram_rel_err", f"{gram_rel:.2e}",
          "max over layers of ||R_d^T R_d - R_s^T R_s||/||R_s^T R_s||; "
          "acceptance: < 1e-3")
-    if not payload["gram_rel"] < 1e-3:        # enforced, not just printed
+    if not gram_rel < 1e-3:                   # enforced, not just printed
         raise RuntimeError(
             f"sharded-vs-single R parity regressed: gram_rel "
-            f"{payload['gram_rel']:.2e} >= 1e-3")
+            f"{gram_rel:.2e} >= 1e-3")
 
 
 # ---------------------------------------------------------------------------
@@ -873,6 +866,7 @@ def main() -> None:
                          "as a per-PR artifact)")
     args = ap.parse_args()
     SMOKE = args.smoke
+    compile_cache.enable()
     names = args.names or list(ALL)
     unknown = [n for n in names if n not in ALL]
     if unknown:

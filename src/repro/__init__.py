@@ -1,9 +1,1 @@
-"""repro: COALA reproduction framework (compression + serving + training).
-
-Importing the package installs the jax compatibility shims from
-``repro.dist.compat`` (``jax.shard_map``, ``jax.sharding.AxisType``,
-``jax.make_mesh(axis_types=…)``) so code written against newer jax APIs —
-including the distributed test scenarios that spawn fresh interpreters —
-runs on the pinned container jax.
-"""
-import repro.dist  # noqa: F401  (side effect: compat.install())
+"""repro: COALA reproduction framework (compression + serving + training)."""

@@ -17,6 +17,7 @@ from typing import Dict, Iterable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import highest_precision
 from repro.core.tsqr import RStreamer, square_r
 from repro.kernels import ops as kops
 from repro.models.linear import CaptureDict
@@ -86,6 +87,7 @@ class Calibrator:
         return {p: s.tokens_seen for p, s in self.streams.items()}
 
 
+@highest_precision
 def calibrate_model(model, params, batches: Iterable[dict], *,
                     collect_gram: bool = False) -> Calibrator:
     """Run capture over calibration batches; returns the filled Calibrator."""

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import tsqr as tsqr_lib
+from repro.core.precision import highest_precision
 
 
 # ---------------------------------------------------------------------------
@@ -72,6 +73,7 @@ class CoalaResult:
     r_factor: jax.Array   # the (possibly μ-augmented) R that was factored
 
     @property
+    @highest_precision
     def w_approx(self) -> jax.Array:
         return self.a @ self.b
 
@@ -101,6 +103,7 @@ def _factor_from_r_rsvd(w: jax.Array, r_factor: jax.Array, r: int,
     return u_r, u_r.T @ w
 
 
+@highest_precision
 def coala_factors(
     w: jax.Array,
     x: Optional[jax.Array] = None,
@@ -179,6 +182,7 @@ def alpha_weight_factor(x_or_r: jax.Array, alpha: float, *, is_r: bool = False) 
     return (v * (s_full ** alpha)[None, :]) @ v.T
 
 
+@highest_precision
 def coala_alpha_factors(w: jax.Array, x: Optional[jax.Array] = None, *,
                         r_factor: Optional[jax.Array] = None,
                         rank: int, alpha: float = 1.0,
@@ -229,6 +233,7 @@ def eym_truncate(a: jax.Array, rank: int) -> jax.Array:
     return (u[:, :rank] * s[:rank][None, :]) @ vt[:rank, :]
 
 
+@highest_precision
 def weighted_error(w: jax.Array, w_approx: jax.Array, x: jax.Array) -> jax.Array:
     """||(W − W')X||_F — the objective of problem (3)."""
     return jnp.linalg.norm((w - w_approx) @ x)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.config import CompressConfig
 from repro.core import baselines as bl
 from repro.core import coala as coala_lib
+from repro.core.precision import highest_precision
 from repro.core.theory import optimal_weighted_error
 from repro.models.linear import rank_for_ratio
 
@@ -206,6 +207,7 @@ def rank_map_from_reports(reports) -> Dict[str, int]:
             if not re.search(r"/e\d+$", r.path)}
 
 
+@highest_precision
 def compress_model(model, params, calibrator, ccfg: CompressConfig, *,
                    rank_map: Optional[Dict[str, int]] = None):
     """End-to-end: calibrator R factors -> compressed params + report.

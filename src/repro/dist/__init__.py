@@ -1,15 +1,9 @@
 """``repro.dist``: mesh sharding + distributed calibration subsystem.
 
-Importing this package installs the jax compatibility shims (see
-``repro.dist.compat``) so the sharding/shard_map code paths run on the
-pinned container jax. Submodules:
+Submodules:
 
   * ``sharding``  — PartitionSpec trees for params / train state / batches /
                     KV caches across every config in ``repro.configs``
   * ``calibrate`` — data-parallel Gram-free COALA calibration (butterfly
                     TSQR reduction of per-shard R factors)
-  * ``compat``    — jax.shard_map / AxisType / make_mesh(axis_types=) shims
 """
-from repro.dist import compat
-
-compat.install()

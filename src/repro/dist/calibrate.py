@@ -7,8 +7,8 @@ compose by QR-stacking. So calibration shards the *token rows* over the
 ``data`` mesh axis:
 
   1. every shard streams its own activation rows into per-layer local R
-     factors (``core.calibrate.Calibrator`` — the same TSQR streaming as the
-     single-device path, never materializing X);
+     factors (the same TSQR fold as the single-device
+     ``core.calibrate.Calibrator``, never materializing X);
   2. the per-shard R factors reduce with the butterfly
      ``core.tsqr.distributed_tsqr_r`` inside ``shard_map`` — log2(shards)
      ppermute+QR rounds, after which every device holds the identical full
@@ -26,36 +26,27 @@ path squares the condition number before it ever reduces; the QR path
 reduces already-orthogonalized factors, which is why ill-conditioned
 calibration survives sharding here and not in Gram-based baselines.
 
-On this CPU container the per-shard capture runs as a host loop over the
-shards of each batch (one fake device per shard); on a real fleet each host
-runs step 1 on its local data and only step 2 touches the interconnect.
+The batch goes onto the mesh sharded over ``data`` and the params
+replicated, so each shard's sub-batch, a replica of the params and its R
+factors sit on that shard's own device. The capture forward then runs as
+one SPMD program per op over the whole mesh, compiled once for it (not
+once per device), and every shard folds its own rows into its own R
+inside ``shard_map``. Only step 2 touches the interconnect.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Iterable, List
+from typing import Dict, Iterable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.calibrate import Calibrator
-from repro.core.tsqr import distributed_tsqr_r, qr_r, square_r, tsqr_tree
-from repro.dist import compat
+from repro.core.precision import highest_precision
+from repro.core.tsqr import distributed_tsqr_r, qr_r, square_r, stack_qr
 from repro.obs import trace
-
-compat.install()
-
-
-def split_batch(batch: dict, n_shards: int) -> List[dict]:
-    """Row-split every batch leaf into ``n_shards`` equal sub-batches."""
-    b = jax.tree.leaves(batch)[0].shape[0]
-    if b % n_shards:
-        raise ValueError(f"batch rows {b} not divisible by {n_shards} shards")
-    per = b // n_shards
-    return [jax.tree.map(lambda x: x[s * per:(s + 1) * per], batch)
-            for s in range(n_shards)]
 
 
 @functools.lru_cache(maxsize=None)
@@ -69,24 +60,78 @@ def _butterfly_reduce_fn(mesh, axis: str):
         check_vma=False))
 
 
-def combine_r_shards(r_stack: jax.Array, mesh, axis: str = "data") -> jax.Array:
-    """Reduce per-shard R factors ``(S, n, n)`` to one full R on-mesh.
+@functools.lru_cache(maxsize=None)
+def _fold_fn(mesh, axis: str, max_tokens: int, first: bool):
+    """Per-shard TSQR fold as one SPMD program: each shard folds its own
+    rows of Xᵀ ``(k / S, n)``, in chunks of ``max_tokens``, into its own R
+    (``(S, n, n)`` sharded over ``axis``). Cached per (mesh, axis,
+    chunking, first fold), so each activation shape compiles once."""
+    def fold(xt, r=None):
+        r = None if first else r[0]
+        for i in range(0, xt.shape[0], max_tokens):
+            c = xt[i:i + max_tokens]
+            r = qr_r(c) if r is None else stack_qr(r, c)
+        return square_r(r)[None]
+
+    in_specs = (P(axis, None),) + (() if first else (P(axis, None, None),))
+    return jax.jit(jax.shard_map(fold, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(axis, None, None),
+                                 check_vma=False))
+
+
+def combine_r_shards(r_shards, mesh, axis: str = "data") -> jax.Array:
+    """Reduce per-shard R factors, an ``(S, n, n)`` array (or a list of S
+    ``(n, n)`` factors), to one full R on-mesh.
 
     Runs the butterfly TSQR over ``axis`` inside ``shard_map``: each device
     holds its shard's R, pairs XOR-wise through ``ppermute``, and after
     log2(S) QR rounds every device holds the identical combined R (returned
     replicated). ``S`` must equal ``mesh.shape[axis]`` (power of two).
     """
+    rs = jnp.stack(r_shards) if isinstance(r_shards, (list, tuple)) \
+        else r_shards
     size = mesh.shape[axis]
-    if r_stack.shape[0] != size:
+    if rs.shape[0] != size:
         raise ValueError(
-            f"r_stack has {r_stack.shape[0]} shards, mesh axis {axis!r} "
-            f"has size {size}")
+            f"{rs.shape[0]} R shards, mesh axis {axis!r} has size {size}")
     with trace.span("calib.butterfly_reduce", shards=size,
-                    n=int(r_stack.shape[-1])):
+                    n=int(rs.shape[-1])):
         if size == 1:
-            return square_r(qr_r(r_stack[0]))
-        return _butterfly_reduce_fn(mesh, axis)(r_stack)
+            return square_r(qr_r(rs[0]))
+        return _butterfly_reduce_fn(mesh, axis)(jax.device_put(
+            rs, NamedSharding(mesh, P(axis, None, None))))
+
+
+class _ShardCalibrator(Calibrator):
+    """Capture sink that folds each record into per-shard R factors: the
+    rows of a record are split over ``axis`` as the batch is, and shard
+    ``s`` folds its rows into ``stacks[path][s]`` on its own device."""
+
+    def __init__(self, mesh, axis: str):
+        super().__init__()
+        self.mesh, self.axis = mesh, axis
+        self.n_shards = mesh.shape[axis]
+        self.rows = NamedSharding(mesh, P(axis, None))
+        self.stacks: Dict[str, jax.Array] = {}
+        self.tokens: Dict[str, int] = {}
+
+    def record(self, path: str, x: jax.Array):
+        n = x.shape[-1]
+        flat = jnp.asarray(x, self.dtype).reshape(-1, n)
+        k = flat.shape[0]
+        pad = -k % self.n_shards
+        if pad:
+            # rows not split like the batch (a MoE expert's routed tokens):
+            # zero rows leave RᵀR, and so R, unchanged
+            flat = jnp.concatenate([flat, jnp.zeros((pad, n), flat.dtype)])
+        flat = jax.device_put(flat, self.rows)
+        with trace.span("calib.record", path=path, tokens=k):
+            prev = self.stacks.get(path)
+            fold = _fold_fn(self.mesh, self.axis, self.max_tokens,
+                            prev is None)
+            self.stacks[path] = fold(flat) if prev is None \
+                else fold(flat, prev)
+            self.tokens[path] = self.tokens.get(path, 0) + k
 
 
 @dataclasses.dataclass
@@ -97,6 +142,8 @@ class ShardedCalibration:
     factors: Dict[str, jax.Array]
     tokens: Dict[str, int]
     n_shards: int
+    # device that held each shard's R factors before the reduce
+    shard_devices: Tuple[jax.Device, ...] = ()
 
     def r_factors(self) -> Dict[str, jax.Array]:
         return dict(self.factors)
@@ -105,41 +152,38 @@ class ShardedCalibration:
         return dict(self.tokens)
 
 
+@highest_precision
 def calibrate_sharded(model, params, batches: Iterable[dict], mesh, *,
                       axis: str = "data") -> ShardedCalibration:
     """Shard calibration rows over ``mesh`` axis ``axis``; butterfly-reduce
     per-shard R factors. Returns per-layer full R factors (replicated).
 
-    Paths that only some shards observed (MoE experts routed on a subset of
-    shards) are combined host-side with the serial TSQR tree over the shards
-    that saw them — still Gram-free, just off the collective fast path.
+    Every batch's leading (row) dimension must divide by the axis size.
     """
+    # the model's ops carry no sharding annotations: let the compiler
+    # propagate the batch sharding (Explicit axes would demand them)
+    mesh = jax.sharding.Mesh(mesh.devices, mesh.axis_names, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
     n = mesh.shape[axis]
-    shard_cals = [Calibrator() for _ in range(n)]
+    cal = _ShardCalibrator(mesh, axis)
+    params = jax.device_put(params, NamedSharding(mesh, P()))
+    rows = NamedSharding(mesh, P(axis))
     n_batches = 0
     for batch in batches:
         n_batches += 1
-        for cal, sub in zip(shard_cals, split_batch(batch, n)):
-            model.capture_forward(params, sub, cal)
+        b = jax.tree.leaves(batch)[0].shape[0]
+        if b % n:
+            raise ValueError(f"batch rows {b} not divisible by {n} shards")
+        model.capture_forward(params, jax.device_put(batch, rows), cal)
     if n_batches == 0:
         raise ValueError("calibrate_sharded: no calibration batches")
 
-    all_paths: List[str] = []
-    for cal in shard_cals:
-        for p in cal.streams:
-            if p not in all_paths:
-                all_paths.append(p)
-
-    factors: Dict[str, jax.Array] = {}
-    tokens: Dict[str, int] = {}
-    for path in all_paths:
-        locals_ = [square_r(cal.streams[path].r)
-                   for cal in shard_cals if path in cal.streams]
-        tokens[path] = sum(cal.streams[path].tokens_seen
-                           for cal in shard_cals if path in cal.streams)
-        if len(locals_) == n:
-            factors[path] = combine_r_shards(jnp.stack(locals_), mesh,
-                                             axis=axis)
-        else:                      # partial coverage (per-expert MoE paths)
-            factors[path] = square_r(tsqr_tree(locals_))
-    return ShardedCalibration(factors=factors, tokens=tokens, n_shards=n)
+    held: Dict[int, jax.Device] = {}
+    for shard in next(iter(cal.stacks.values())).addressable_shards:
+        held.setdefault(shard.index[0].start or 0, shard.device)
+    factors = {path: combine_r_shards(stack, mesh, axis=axis)
+               for path, stack in cal.stacks.items()}
+    return ShardedCalibration(factors=factors, tokens=dict(cal.tokens),
+                              n_shards=n,
+                              shard_devices=tuple(held[i] for i in
+                                                  sorted(held)))

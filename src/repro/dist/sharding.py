@@ -32,10 +32,6 @@ from typing import Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
-
-compat.install()
-
 MODEL_AXIS = "model"
 # batch-like axes in mesh-major order; only those present in a mesh apply
 BATCH_AXES = ("pod", "data")

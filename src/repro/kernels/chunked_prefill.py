@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro.kernels.paged_attention import gather_pages
 
 NEG_INF = -1e30
 
@@ -58,7 +58,7 @@ NEG_INF = -1e30
 def _kernel(tbl_ref, start_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *,
             scale: float, cap: float, window: int,
-            bs: int, bq: int, nb: int):
+            bs: int, bq: int, g: int, nb: int):
     b = pl.program_id(0)
     qi = pl.program_id(2)
     i = pl.program_id(3)
@@ -80,33 +80,34 @@ def _kernel(tbl_ref, start_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                    # (bq, G, hd)
-        k = k_ref[0, :, 0]                 # (bs, hd)
-        v = v_ref[0, :, 0]
-        g, hd = q.shape[1], q.shape[2]
-        s = jnp.dot(q.reshape(bq * g, hd), k.T,
-                    preferred_element_type=jnp.float32) * scale
-        s = s.reshape(bq, g, bs)
-        if cap > 0:
-            s = cap * jnp.tanh(s / cap)
-        iq = q_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k = k_ref[0, 0]                    # (bs, hd)
+        v = v_ref[0, 0]
+        shape = (bq, bs)
+        iq = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         ok = iq < total                    # padded queries (j >= lens) -> 0 rows
-        ik = i * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        ik = i * bs + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         ok &= ik <= iq                     # causal, offset by the cached prefix
         if window > 0:
             ok &= (iq - ik) < window
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]                # (bq, G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # fully-masked query rows keep m == NEG_INF; shift the exponent so
-        # they contribute p = 0 (exp(NEG_INF - NEG_INF) would be 1)
-        p = jnp.exp(s - jnp.maximum(m_new, NEG_INF / 2))
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.reshape(bq * g, bs).astype(v.dtype), v,
-            preferred_element_type=jnp.float32).reshape(bq, g, hd)
-        m_ref[...] = m_new
+        # one (bq, hd) x (hd, bs) product per query head sharing this KV
+        # head: folding G into the row axis would need a sublane reshape
+        # Mosaic refuses for G = 3
+        for h in range(g):
+            s = jnp.dot(q_ref[0, 0, h], k.T,
+                        preferred_element_type=jnp.float32) * scale
+            if cap > 0:
+                s = cap * jnp.tanh(s / cap)
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_ref[h]              # (bq, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # fully-masked query rows keep m == NEG_INF; shift the exponent
+            # so they contribute p = 0 (exp(NEG_INF - NEG_INF) would be 1)
+            p = jnp.exp(s - jnp.maximum(m_new, NEG_INF / 2))
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(i == nb - 1)
     def _finalize():
@@ -125,8 +126,8 @@ def chunked_prefill(q, k_pages, v_pages, block_tables, starts, lens, *,
 
     q: (B, L, Hq, hd) — each row's suffix queries, rotary already applied,
       right-padded to the shared length bucket ``L``.
-    k_pages/v_pages: (num_blocks, bs, Hkv, hd) — the shared page stores,
-      already holding the new suffix K/V (the caller scatters them in).
+    k_pages/v_pages: (num_blocks, Hkv, bs, hd) — the shared head-major page
+      stores, already holding the new suffix K/V (the caller scatters them in).
     block_tables: (B, nb) int32 — physical page ids per request, ragged rows
       padded with the trash page (0).
     starts: (B,) int32 — cached-prefix length per row (the global position
@@ -138,7 +139,7 @@ def chunked_prefill(q, k_pages, v_pages, block_tables, starts, lens, *,
     Returns (B, L, Hq, hd) in q.dtype.
     """
     b, lq, hq, hd = q.shape
-    nb_total, bs, hkv, _ = k_pages.shape
+    nb_total, hkv, bs, _ = k_pages.shape
     g = hq // hkv
     nb = block_tables.shape[1]
     if scale is None:
@@ -150,7 +151,9 @@ def chunked_prefill(q, k_pages, v_pages, block_tables, starts, lens, *,
         # validity mask zeroes them without any extra bookkeeping
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
     nq = (lq + pad) // bq
-    qg = q.reshape(b, nq * bq, hkv, g, hd).transpose(0, 2, 1, 3, 4)
+    # head-major queries (B, Hkv, G, L, hd): each block's minor dims are a
+    # (bq, hd) tile of one query head
+    qg = q.reshape(b, nq * bq, hkv, g, hd).transpose(0, 2, 3, 1, 4)
     tables = block_tables.astype(jnp.int32)
     st = starts.astype(jnp.int32)
     ln = lens.astype(jnp.int32)
@@ -159,32 +162,32 @@ def chunked_prefill(q, k_pages, v_pages, block_tables, starts, lens, *,
         num_scalar_prefetch=3,             # tables, starts, lens -> SMEM
         grid=(b, hkv, nq, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, g, hd),
-                         lambda bi, h, qi, i, tbl, s, ln: (bi, h, qi, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda bi, h, qi, i, tbl, s, ln: (tbl[bi, i], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda bi, h, qi, i, tbl, s, ln: (tbl[bi, i], 0, h, 0)),
+            pl.BlockSpec((1, 1, g, bq, hd),
+                         lambda bi, h, qi, i, tbl, s, ln: (bi, h, 0, qi, 0)),
+            pl.BlockSpec((1, 1, bs, hd),
+                         lambda bi, h, qi, i, tbl, s, ln: (tbl[bi, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hd),
+                         lambda bi, h, qi, i, tbl, s, ln: (tbl[bi, i], h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, g, hd),
-                               lambda bi, h, qi, i, tbl, s, ln: (bi, h, qi, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, bq, hd),
+                               lambda bi, h, qi, i, tbl, s, ln: (bi, h, 0, qi, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bq, g, 1), jnp.float32),   # running max m
-            pltpu.VMEM((bq, g, 1), jnp.float32),   # running denom l
-            pltpu.VMEM((bq, g, hd), jnp.float32),  # output accumulator
+            pltpu.VMEM((g, bq, 1), jnp.float32),   # running max m
+            pltpu.VMEM((g, bq, 1), jnp.float32),   # running denom l
+            pltpu.VMEM((g, bq, hd), jnp.float32),  # output accumulator
         ],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, cap=cap, window=window,
-                          bs=bs, bq=bq, nb=nb),
+                          bs=bs, bq=bq, g=g, nb=nb),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, nq * bq, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, nq * bq, hd), q.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
     )(tables, st, ln, qg, k_pages, v_pages)
-    out = out.transpose(0, 2, 1, 3, 4).reshape(b, nq * bq, hq, hd)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, nq * bq, hq, hd)
     return out[:, :lq]
 
 
@@ -197,13 +200,13 @@ def chunked_prefill_ref(q, k_pages, v_pages, block_tables, starts, lens, *,
     same per-row prefix-offset causal semantics as the kernel.
     """
     b, lq, hq, hd = q.shape
-    bs, hkv = k_pages.shape[1], k_pages.shape[2]
+    hkv, bs = k_pages.shape[1], k_pages.shape[2]
     g = hq // hkv
     nb = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
-    k = k_pages[block_tables].reshape(b, nb * bs, hkv, hd)
-    v = v_pages[block_tables].reshape(b, nb * bs, hkv, hd)
+    k = gather_pages(k_pages, block_tables)
+    v = gather_pages(v_pages, block_tables)
     qg = q.reshape(b, lq, hkv, g, hd)
     s = jnp.einsum("blkgd,bskd->bkgls", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -104,7 +102,7 @@ def flash_attention(q, k, v, *, scale=None, cap: float = 0.0,
             pltpu.VMEM((bq, hd), jnp.float32),      # output accumulator
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qh, kh, vh)
     return out.reshape(b, hq, t, hd).transpose(0, 2, 1, 3)

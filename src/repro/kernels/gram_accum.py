@@ -17,8 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(ai_ref, aj_ref, o_ref):
@@ -54,6 +53,6 @@ def gram_accum(a, *, block_i: int = 256, block_j: int = 256,
         out_specs=pl.BlockSpec((bi, bj), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, a)

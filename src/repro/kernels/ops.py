@@ -13,7 +13,6 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import gram_accum as _ga
 from repro.kernels import lowrank_linear as _ll
 from repro.kernels import paged_attention as _pa
-from repro.kernels.compat import tpu_compiler_params  # noqa: F401  (re-export)
 
 
 def _interpret() -> bool:

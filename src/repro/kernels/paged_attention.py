@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -65,8 +64,8 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0]                        # (G, hd)
-        k = k_ref[0, :, 0]                     # (bs, hd)
-        v = v_ref[0, :, 0]
+        k = k_ref[0, 0]                        # (bs, hd)
+        v = v_ref[0, 0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if cap > 0:
             s = cap * jnp.tanh(s / cap)
@@ -99,7 +98,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """Decode-step attention over a paged KV cache.
 
     q: (B, Hq, hd) — one query token per request, already rotary-embedded.
-    k_pages/v_pages: (num_blocks, bs, Hkv, hd) — the shared block pool.
+    k_pages/v_pages: (num_blocks, Hkv, bs, hd) — the shared block pool,
+      head-major so each (page, head) block is a full ``(bs, hd)`` tile.
     block_tables: (B, nb) int32 — physical page ids per request, ragged rows
       padded with the trash block (0).
     lengths: (B,) int32 — valid positions per request (query at length-1);
@@ -108,7 +108,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     Returns (B, Hq, hd) in q.dtype.
     """
     b, hq, hd = q.shape
-    nb_total, bs, hkv, _ = k_pages.shape
+    nb_total, hkv, bs, _ = k_pages.shape
     g = hq // hkv
     nb = block_tables.shape[1]
     if scale is None:
@@ -122,10 +122,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         grid=(b, hkv, nb),
         in_specs=[
             pl.BlockSpec((1, 1, g, hd), lambda bi, h, i, tbl, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda bi, h, i, tbl, ln: (tbl[bi, i], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd),
-                         lambda bi, h, i, tbl, ln: (tbl[bi, i], 0, h, 0)),
+            pl.BlockSpec((1, 1, bs, hd),
+                         lambda bi, h, i, tbl, ln: (tbl[bi, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hd),
+                         lambda bi, h, i, tbl, ln: (tbl[bi, i], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd),
                                lambda bi, h, i, tbl, ln: (bi, h, 0, 0)),
@@ -141,10 +141,19 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(tables, lens, qg, k_pages, v_pages)
     return out.reshape(b, hq, hd)
+
+
+def gather_pages(pages, block_tables):
+    """(num_blocks, Hkv, bs, hd) pages named by (B, nb) tables ->
+    contiguous (B, nb * bs, Hkv, hd) token-major K/V."""
+    b, nb = block_tables.shape
+    _, hkv, bs, hd = pages.shape
+    g = pages[block_tables]                          # (B, nb, Hkv, bs, hd)
+    return g.transpose(0, 1, 3, 2, 4).reshape(b, nb * bs, hkv, hd)
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
@@ -155,13 +164,13 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     inside the surrounding jit) and runs a masked softmax in fp32.
     """
     b, hq, hd = q.shape
-    bs, hkv = k_pages.shape[1], k_pages.shape[2]
+    hkv, bs = k_pages.shape[1], k_pages.shape[2]
     g = hq // hkv
     nb = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
-    k = k_pages[block_tables].reshape(b, nb * bs, hkv, hd)
-    v = v_pages[block_tables].reshape(b, nb * bs, hkv, hd)
+    k = gather_pages(k_pages, block_tables)
+    v = gather_pages(v_pages, block_tables)
     qg = q.reshape(b, hkv, g, hd)
     s = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale

@@ -55,6 +55,7 @@ from repro.config import CompressConfig, TrainConfig  # noqa: E402
 from repro.configs import get_config, get_smoke_config  # noqa: E402
 from repro.core.calibrate import calibrate_model  # noqa: E402
 from repro.core.compress import compress_model, compression_summary  # noqa: E402
+from repro import compile_cache  # noqa: E402
 from repro.ckpt import CheckpointManager  # noqa: E402
 from repro.data import DataConfig, TokenPipeline  # noqa: E402
 from repro.dist.calibrate import calibrate_sharded  # noqa: E402
@@ -97,13 +98,14 @@ def main():
                     help="write a Chrome/Perfetto trace_event JSON of the "
                          "calibration/compression spans to this path")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.trace_out:
         obs_trace.enable()
     if args.mesh:
         # fail fast (before minutes of pretrain/eval): _peek_mesh swallows
         # malformed values, so a typo would silently fall back to the
         # single-device path, and bad shard counts would only crash deep
-        # inside split_batch / the butterfly TSQR after the expensive phase
+        # inside calibrate_sharded after the expensive phase
         if not _MESH or set(_MESH) != {"data"}:
             ap.error(f"--mesh {args.mesh!r} not understood; expected "
                      f"'data=N' (calibration shards over the data axis)")

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import compile_cache
 from repro.config import SHAPES, TrainConfig
 from repro.configs import ARCH_IDS, get_config
 from repro.dist.sharding import (batch_axes_of, batch_specs, cache_specs,
@@ -279,6 +280,7 @@ def main():
     ap.add_argument("--mlstm-chunkwise", action="store_true")
     ap.add_argument("--tag", default="")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.all:
         fails = 0

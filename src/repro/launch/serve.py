@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.config import CompressConfig
 from repro.configs import get_config, get_smoke_config
 from repro.core.calibrate import calibrate_model
@@ -87,6 +88,13 @@ def _compressed_params(cfg, model, params, pipe, ratio: float,
     return cparams, None, reports, None
 
 
+def _serve_dtype(args):
+    """Engine compute and cache dtype: the engine's bf16 default, except
+    float32 under ``--smoke`` (the CPU preset, where the greedy parity
+    checks compare against float32 oracles)."""
+    return jnp.float32 if args.smoke else jnp.bfloat16
+
+
 def _parse_buckets(spec: str):
     """'1,2,4,8' -> (1, 2, 4, 8); empty -> None (engine default)."""
     return tuple(int(s) for s in spec.split(",") if s.strip()) or None
@@ -134,8 +142,8 @@ def _run_continuous(args, cfg, model, params, pipe, *, server, flight,
     warm_len = max(len(p) + nn for _, p, nn in trace)
     eng = None
     for name, p in (("dense", params), ("coala", cparams)):
-        eng = ContinuousEngine(model, p, compute_dtype=jnp.float32,
-                               cache_dtype=jnp.float32,
+        eng = ContinuousEngine(model, p, compute_dtype=_serve_dtype(args),
+                               cache_dtype=_serve_dtype(args),
                                block_size=args.block_size,
                                num_blocks=args.num_blocks,
                                max_running=args.max_running,
@@ -244,8 +252,8 @@ def run_fixed(args, cfg, model, params, pipe):
     if args.compress_ratio > 0:
         params, _, _, _ = _compressed_params(cfg, model, params, pipe,
                                              args.compress_ratio)
-    eng = ServeEngine(model, params, compute_dtype=jnp.float32,
-                      cache_dtype=jnp.float32)
+    eng = ServeEngine(model, params, compute_dtype=_serve_dtype(args),
+                      cache_dtype=_serve_dtype(args))
     batch = pipe.get_batch(0)
     extras = {k: v for k, v in batch.items() if k != "tokens"}
     out = eng.generate(batch["tokens"], max_new_tokens=args.new_tokens,
@@ -375,6 +383,7 @@ def main():
                          "serve_slo_goodput gauge (0 = unset)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.trace_out:
         obs_trace.enable(max_events=args.trace_max_events or None)

@@ -38,6 +38,7 @@ import time  # noqa: E402
 
 import jax  # noqa: E402
 
+from repro import compile_cache  # noqa: E402
 from repro.ckpt import CheckpointManager  # noqa: E402
 from repro.config import TrainConfig  # noqa: E402
 from repro.configs import get_config, get_smoke_config  # noqa: E402
@@ -76,6 +77,7 @@ def main():
     ap.add_argument("--process-id", type=int, default=0)
     ap.add_argument("--num-processes", type=int, default=1)
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.coordinator:
         jax.distributed.initialize(args.coordinator, args.num_processes,

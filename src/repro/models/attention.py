@@ -238,22 +238,24 @@ def gqa_apply(cfg, params, x, *, ctx: ParallelCtx, cos_sin=None,
     scale = cfg.query_scale if cfg.query_scale > 0 else None
     new_cache = None
     if paged_tables is not None:
-        # paged serving: the cache leaves are the pool's page stores
-        # (num_blocks, block_size, hkv, hd); write the new K/V straight into
-        # their pages and attend through the block-table indirection — no
-        # contiguous copy of the KV history is ever materialized. t == 1 is
-        # a decode step; t > 1 is a chunked suffix prefill writing row i's L
-        # tokens at positions pos[i] + j (padded tail tokens past lens[i]
-        # land in the row's last partial page or the trash page, hidden by
-        # the causal masks until a later decode overwrites them).
+        # paged serving: the cache leaves are the pool's head-major page
+        # stores (num_blocks, hkv, block_size, hd); write the new K/V
+        # straight into their pages and attend through the block-table
+        # indirection — no contiguous copy of the KV history is ever
+        # materialized. t == 1 is a decode step; t > 1 is a chunked suffix
+        # prefill writing row i's L tokens at positions pos[i] + j (padded
+        # tail tokens past lens[i] land in the row's last partial page or
+        # the trash page, hidden by the causal masks until a later decode
+        # overwrites them).
         assert pos is not None and jnp.ndim(pos) == 1, \
             "paged path needs per-request positions"
         from repro.kernels import ops as kops
-        bs = cache["k"].shape[1]
+        bs = cache["k"].shape[2]
         p = pos[:, None] + jnp.arange(t)                 # (B, t) positions
         blk = jnp.take_along_axis(paged_tables, p // bs, axis=1)
-        kf = cache["k"].at[blk, p % bs].set(k.astype(cache["k"].dtype))
-        vf = cache["v"].at[blk, p % bs].set(v.astype(cache["v"].dtype))
+        # (blk, :, slot) indexes (B, t) x hkv -> updates shaped (B, t, hkv, hd)
+        kf = cache["k"].at[blk, :, p % bs].set(k.astype(cache["k"].dtype))
+        vf = cache["v"].at[blk, :, p % bs].set(v.astype(cache["v"].dtype))
         if t == 1:
             o = kops.paged_attention(
                 q[:, 0], kf, vf, paged_tables, pos + 1, scale=scale,

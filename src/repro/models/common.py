@@ -48,21 +48,9 @@ def constrain_act(x, ctx: "ParallelCtx"):
     from jax.sharding import AxisType, NamedSharding, PartitionSpec
     # inside a partially-manual shard_map (e.g. the pod-manual gradient
     # compression region) the manual axes may not appear in constraints
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        manual = {n for n, t in zip(am.axis_names, am.axis_types)
-                  if t == AxisType.Manual}
-    except Exception:
-        # pinned jax (no abstract-mesh API): its SPMD partitioner cannot
-        # express full-mesh constraints inside a manual subgroup at all
-        # (hlo_sharding_util CHECK) — drop the layout hint there entirely.
-        # A nonempty axis env means we are under shard_map/pmap.
-        try:
-            if jax.core.nonempty_axis_env_DO_NOT_USE():
-                return x
-        except Exception:
-            pass
-        manual = set()
+    am = jax.sharding.get_abstract_mesh()
+    manual = {n for n, t in zip(am.axis_names, am.axis_types)
+              if t == AxisType.Manual}
     axes = tuple(a for a in ctx.batch_axes if a not in manual)
     if not axes:
         return x

@@ -214,8 +214,6 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str,
     scan-aware global flops/bytes; XLA's cost_analysis counts while bodies
     once and is kept only as a diagnostic."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):      # pinned jax: one dict per program
-        ca = ca[0] if ca else {}
     hlo_flops_once = float(ca.get("flops", 0.0))
     hlo_bytes_once = float(ca.get("bytes accessed", 0.0))
     if jaxpr_cost is not None:

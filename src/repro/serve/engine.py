@@ -438,7 +438,9 @@ class ContinuousEngine:
                 donate_argnums=(2,))
         else:
             self._prefill_chunk_paged = None
-        self._sample = jax.jit(_sample_rows)
+        # sampled tokens plus, per row, whether every logit was finite
+        self._sample = jax.jit(lambda lg, t, k: (
+            _sample_rows(lg, t, k), jnp.all(jnp.isfinite(lg), axis=-1)))
         if self._spec:
             spec_steps = self.spec_k + 1
 
@@ -1237,14 +1239,19 @@ class ContinuousEngine:
 
     def _sample_tokens(self, logits, reqs, pad_to: int = 0) -> np.ndarray:
         """Row-wise sampling; rows past ``len(reqs)`` are bucket padding
-        (sampled greedily on garbage logits, discarded by the caller)."""
+        (sampled greedily on garbage logits, discarded by the caller). A
+        request whose logits held a NaN or inf is marked
+        ``logits_finite = False``."""
         pad = max(pad_to - len(reqs), 0)
         temps = jnp.asarray([r.temperature for r in reqs] + [0.0] * pad,
                             jnp.float32)
         keys = jnp.stack([
             jax.random.fold_in(jax.random.PRNGKey(r.seed), len(r.out_tokens))
             for r in reqs] + [jax.random.PRNGKey(0)] * pad)
-        return np.asarray(self._sample(logits, temps, keys))[:len(reqs)]
+        toks, finite = self._sample(logits, temps, keys)
+        for r, ok in zip(reqs, np.asarray(finite)):
+            r.logits_finite &= bool(ok)
+        return np.asarray(toks)[:len(reqs)]
 
     def _prefill_request(self, req: Request) -> None:
         with trace.span("serve.prefill_request", req_id=req.req_id,

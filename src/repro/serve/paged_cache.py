@@ -13,13 +13,16 @@ The pool owns all KV storage as fixed-size token pages plus a per-request
 state store, and exposes two read paths:
 
   * **paged** (the decode hot path): ``paged_cache()`` hands the model the
-    page stores *themselves* — token leaves are kept in the leaf's original
-    axis order with the (batch, token) axes replaced by (num_blocks,
-    block_size), so a stacked-blocks leaf ``(n_rep, B, T, Hkv, hd)`` is
-    stored as ``(n_rep, num_blocks, bs, Hkv, hd)`` and slots zero-copy into
-    the model's layer scan. The attention layers read the block-table
-    indirection directly (``kernels/paged_attention.py``) and write the new
-    token into its page in place; ``absorb_paged()`` then just swaps array
+    page stores *themselves* — a token leaf's batch axis becomes the page
+    axis (``num_blocks``) and its in-page token axis (``block_size``) sits
+    second-minor, so a stacked-blocks leaf ``(n_rep, B, T, Hkv, hd)`` is
+    stored head-major as ``(n_rep, num_blocks, Hkv, bs, hd)``: one page of
+    one head is a whole ``(bs, hd)`` tile, the block the TPU kernels can
+    DMA (a ``(bs, 1, hd)`` slice of a token-major page is not), and the
+    store slots zero-copy into the model's layer scan. The attention
+    layers read the block-table indirection directly
+    (``kernels/paged_attention.py``) and write the new token into its page
+    in place; ``absorb_paged()`` then just swaps array
     references. No per-step gather or scatter of the cache.
   * **gather** (fallback/oracle): ``gather_batch`` indexes the pool with a
     padded ``(B, nb)`` block-table matrix to assemble exactly the pytree
@@ -87,6 +90,12 @@ class LeafSpec:
                                   else 0)
 
     @property
+    def in_page_axis(self) -> int:
+        """Position of the in-page token axis in the token store: second-
+        minor, or last when no axis follows the token axis."""
+        return self.blocks_axis + max(len(self.tail) - self.blocks_axis, 1)
+
+    @property
     def slot_axis(self) -> int:
         """Position of the slot axis in the state store."""
         return self.batch_axis
@@ -127,8 +136,9 @@ class CacheLayout:
 
 
 def _token_store_shape(sp: LeafSpec, num_blocks: int, block_size: int):
-    ax = sp.blocks_axis
-    return sp.tail[:ax] + (num_blocks, block_size) + sp.tail[ax:]
+    ax, ip = sp.blocks_axis, sp.in_page_axis
+    shape = sp.tail[:ax] + (num_blocks,) + sp.tail[ax:]
+    return shape[:ip] + (block_size,) + shape[ip:]
 
 
 def _state_store_shape(sp: LeafSpec, n_slots: int):
@@ -633,9 +643,10 @@ class BlockPool:
 # the pool buffers instead of copying the whole pool every step; the pool
 # immediately replaces its references with the returned arrays.
 #
-# Token stores keep the leaf's original axis order, so indexing happens at
-# ``spec.blocks_axis`` (resp. ``spec.slot_axis``) rather than axis 0; the
-# only data ever transposed is the gathered batch-sized slice, never a pool.
+# Token stores keep the leaf's axis order except for the in-page token axis
+# (``spec.in_page_axis``), so indexing happens at ``spec.blocks_axis``
+# (resp. ``spec.slot_axis``) rather than axis 0; the only data ever
+# transposed is the gathered batch-sized slice, never a pool.
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
@@ -697,7 +708,8 @@ def _gather(specs, block_size, token_store, state_store, tables, slots):
             si += 1
             continue
         ax = sp.blocks_axis
-        g = jnp.take(token_store[ti], tables, axis=ax)   # pre+(B,nb,bs)+post
+        g = jnp.take(token_store[ti], tables, axis=ax)
+        g = jnp.moveaxis(g, sp.in_page_axis + 1, ax + 2)  # pre+(B,nb,bs)+post
         g = g.reshape(g.shape[:ax] + (b, nb * block_size) + g.shape[ax + 3:])
         # batch now sits where the page axis was; restore the original order
         out.append(jnp.moveaxis(g, ax, sp.batch_axis))
@@ -726,6 +738,7 @@ def _scatter_prefill(specs, block_size, nb_used, token_store, state_store,
         # shifted it by one when it followed the token axis)
         b_src = sp.batch_axis + (1 if sp.batch_axis > sp.token_axis else 0)
         blk = jnp.moveaxis(blk, b_src, ax)               # pre+(B,nb,bs)+post
+        blk = jnp.moveaxis(blk, ax + 2, sp.in_page_axis + 1)   # store order
         ids = tables[:, :nb_used]                        # (B, nb_used)
         new_token[ti] = new_token[ti].at[_ix(ax, ids)].set(
             blk.astype(new_token[ti].dtype))
@@ -754,6 +767,7 @@ def _scatter_token(specs, block_size, token_store, state_store,
         )(arr, blk_idx)                                  # (B, bs, *tail)
         ax = sp.blocks_axis
         slab = jnp.moveaxis(slab, (0, 1), (ax, ax + 1))  # pre+(B,bs)+post
+        slab = jnp.moveaxis(slab, ax + 1, sp.in_page_axis)     # store order
         new_token[ti] = new_token[ti].at[_ix(ax, blk_ids)].set(
             slab.astype(new_token[ti].dtype))
         ti += 1

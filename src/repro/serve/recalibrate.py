@@ -64,6 +64,7 @@ import numpy as np
 
 from repro.core.calibrate import Calibrator
 from repro.core.compress import compress_model
+from repro.core.precision import highest_precision
 from repro.obs import numerics, trace
 
 FAIL = numerics.FAIL
@@ -127,6 +128,7 @@ class TrafficCalibrator(Calibrator):
             x = x[:, self._rec_start:]
         super().record(path, x)
 
+    @highest_precision
     def capture(self, base_params, tokens, *, start: int = 0) -> None:
         """Replay ``tokens`` (T,) through the eager capture path, recording
         only positions >= ``start`` (each conditioned on its full prefix)."""

@@ -59,6 +59,7 @@ class Request:
     finish_time: Optional[float] = None
     spec_proposed: int = 0                   # draft tokens proposed for this
     spec_accepted: int = 0                   # request / accepted by the target
+    logits_finite: bool = True               # every sampled logit row finite
 
     @property
     def done(self) -> bool:

@@ -21,10 +21,8 @@ Structure (two phases, keeping the model OUT of any manual region):
      the data/model axes stay ordinary GSPMD code;
   2. ONLY the quantize → psum → dequantize reduction runs inside a
      ``shard_map`` that is manual over the ``pod`` axis. Its body is
-     elementwise math plus one ``psum`` — the only shapes the pinned XLA
-     can partition inside a manual subgroup (a ``scan``, i.e. any real
-     model, inside partial-manual shard_map trips a fatal
-     ``IsManualSubgroup`` check in the pinned partitioner).
+     elementwise math plus one ``psum``; the model itself stays ordinary
+     GSPMD code over (data, model), outside any manual region.
 
 The error state carries an explicit leading pod axis (spec ``P('pod', ...)``)
 so each pod's residual survives round-trips through the global value.
@@ -45,10 +43,7 @@ def _quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % BLOCK
     if pad:
-        # concatenate, not jnp.pad: a Pad HLO inside the pod-manual
-        # shard_map region trips a fatal IsManualSubgroup check in the
-        # pinned XLA partitioner; Concatenate partitions fine
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+        flat = jnp.pad(flat, (0, pad))
     blocks = flat.reshape(-1, BLOCK)
     scale = jnp.max(jnp.abs(blocks), axis=1, keepdims=True) / 127.0
     scale = jnp.maximum(scale, 1e-12)

@@ -92,17 +92,13 @@ def make_train_step(model, tcfg: TrainConfig, ctx: ParallelCtx,
     use_compress = (tcfg.grad_compress_pods and mesh is not None
                     and "pod" in mesh.axis_names)
     if use_compress:
-        import dataclasses as _dc
         # the loss runs under vmap over the explicit pod axis (see
-        # grad_compress.py): layout hints and nested shard_map regions do
-        # not compose with that vmap on the pinned jax, so the per-pod body
-        # drops them — GSPMD still auto-parallelizes over (data, model)
-        ctx_pod = _dc.replace(ctx, batch_axes=(), shard_map_moe=False)
-
+        # grad_compress.py), with the same layout hints and expert-parallel
+        # shard_map as the uncompressed step
         def pod_loss_and_grad(params, batch):
             def loss_fn(p):
                 pc = cast_for_compute(p, compute_dtype)
-                return model.loss(pc, batch, ctx=ctx_pod, remat=tcfg.remat,
+                return model.loss(pc, batch, ctx=ctx, remat=tcfg.remat,
                                   compute_dtype=compute_dtype)
             return jax.value_and_grad(loss_fn, has_aux=True)(params)
 

@@ -48,7 +48,7 @@ def _artifact():
         ("serve/recalib_swap_ms", "45.2"),
         ("serve/recalib_tokens_to_clearance", 81),
         ("serve/recalib_r_gram_rel_err", "5.4e-07"),
-        ("dist/calib_sharded8_tok_per_s", "5400.0"),
+        ("dist/calib_sharded_tok_per_s", "5400.0"),
         ("dist/r_gram_rel_err", "3.1e-07"),
     ]
     return {"benchmarks": ["serve", "dist"], "smoke": True, "errors": {},
@@ -179,7 +179,7 @@ def test_update_defaults_band_for_throughput_only(gate):
     _, _, base_path = gate
     rows = json.loads(base_path.read_text())["rows"]
     assert rows["serve/paged_tok_per_s"]["kind"] == "band"
-    assert rows["dist/calib_sharded8_tok_per_s"]["kind"] == "band"
+    assert rows["dist/calib_sharded_tok_per_s"]["kind"] == "band"
     assert rows["serve/warm_ttft_ms"]["kind"] == "present"
     assert rows["serve/post_warmup_compiles"]["kind"] == "present"
 

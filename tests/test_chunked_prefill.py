@@ -29,8 +29,8 @@ def _case(key, *, b, hq, hkv, hd, bs, num_blocks, starts, lens, lq=None):
     ks = jax.random.split(jax.random.PRNGKey(key), 3)
     lq = lq or max(max(lens), 1)
     q = jax.random.normal(ks[0], (b, lq, hq, hd), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (num_blocks, bs, hkv, hd), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (num_blocks, bs, hkv, hd), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (num_blocks, hkv, bs, hd), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (num_blocks, hkv, bs, hd), jnp.float32)
     totals = [s + l for s, l in zip(starts, lens)]
     nb = max(max(-(-t // bs) for t in totals), 1)
     tables = np.zeros((b, nb), np.int32)
@@ -49,8 +49,9 @@ def _dense_oracle(q, k_pages, v_pages, tables, starts, lens, *, scale=None,
     """Per-row, per-query contiguous softmax attention in fp64; query j of
     row i sits at global position starts[i] + j and attends [0, that]."""
     q = np.asarray(q, np.float64)
-    kp = np.asarray(k_pages, np.float64)
-    vp = np.asarray(v_pages, np.float64)
+    # head-major pages -> token-major (num_blocks, bs, hkv, hd)
+    kp = np.asarray(k_pages, np.float64).transpose(0, 2, 1, 3)
+    vp = np.asarray(v_pages, np.float64).transpose(0, 2, 1, 3)
     tables, starts, lens = map(np.asarray, (tables, starts, lens))
     b, lq, hq, hd = q.shape
     bs, hkv = kp.shape[1], kp.shape[2]

@@ -224,6 +224,41 @@ class TestRSVD:
         assert float(rnd) <= float(exact) * 1.05
 
 
+class TestPrecisionScope:
+    """Calibration and compression entry points run their matmuls at
+    "highest" precision (a single bf16 pass on TPU otherwise);
+    ``__wrapped__`` is the unscoped function."""
+
+    def test_entry_points_run_at_highest(self):
+        from repro.core.precision import highest_precision
+        seen = []
+
+        @highest_precision
+        def probe():
+            seen.append(jax.config.jax_default_matmul_precision)
+
+        probe()
+        probe.__wrapped__()
+        assert seen[0] == "highest"
+        assert jax.config.jax_default_matmul_precision == seen[1]
+        from repro.core.calibrate import calibrate_model
+        from repro.core.compress import compress_model
+        from repro.dist.calibrate import calibrate_sharded
+        from repro.serve.recalibrate import TrafficCalibrator
+        for fn in (coala_factors, coala_alpha_factors, weighted_error,
+                   calibrate_model, calibrate_sharded, compress_model,
+                   TrafficCalibrator.capture):
+            assert hasattr(fn, "__wrapped__"), fn
+
+    def test_unscoped_solve_matches_on_cpu(self):
+        w, x = _rand(24, 16, 40), _rand(16, 48, 41)
+        a = coala_factors(w, x, rank=4)
+        b = coala_factors.__wrapped__(w, x, rank=4)
+        np.testing.assert_allclose(np.asarray(a.w_approx),
+                                   np.asarray(b.a @ b.b), rtol=1e-4,
+                                   atol=1e-5)
+
+
 class TestStability:
     """The paper's Fig. 1 / Example G.1: Gram-based methods lose √ε accuracy."""
 

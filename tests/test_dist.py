@@ -5,11 +5,8 @@ import subprocess
 import sys
 import textwrap
 
-# These subprocesses exercise `repro.dist` (sharding specs + the
-# jax.shard_map/AxisType compat shims installed on `import repro`) on 8 fake
-# host devices. They were xfail(strict=False) from the seed commit until the
-# subsystem landed; they now assert for real. Nothing here is unsupported on
-# the pinned jax 0.4.37 — the shims in repro/dist/compat.py close the gap.
+# These subprocesses exercise `repro.dist` (sharding specs, butterfly TSQR
+# under jax.shard_map) and multi-pod training on 8 fake host devices.
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -94,42 +91,51 @@ class TestMoEShardMap:
         """)
 
 
+def _grad_compress_case(n: int) -> None:
+    run_with_devices(f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro.train import grad_compress as gc
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,)*3)
+
+        def loss_and_grad(params, batch):
+            # per-pod quadratic: grads differ across pods via the batch
+            def loss(p):
+                return jnp.mean((p["w"] * batch["x"] - 1.0) ** 2)
+            l, g = jax.value_and_grad(loss)(params)
+            return (l, {{"ce": l, "aux": jnp.zeros(())}}), g
+
+        f = gc.make_compressed_grads_fn(
+            loss_and_grad, mesh,
+            lambda leaf: P("pod", *([None] * (leaf.ndim - 1))))
+        params = {{"w": jnp.ones(({n},))}}
+        batch = {{"x": jnp.concatenate([jnp.ones((2, {n})),
+                                        2 * jnp.ones((2, {n}))])}}
+        err = gc.init_error_state(params, 2)
+        loss, metrics, grads, new_err = jax.jit(f)(params, batch, err)
+        # true mean-of-pod-grads: per pod, loss = mean over (2,n)
+        # elements; d/dw_i = (1/(2*n)) * sum_rows 2*(w_i*x-1)*x
+        g1 = 2 * 2 * (1.0 - 1.0) * 1.0 / (2 * {n})   # pod 0 (x=1): 0
+        g2 = 2 * 2 * (2.0 - 1.0) * 2.0 / (2 * {n})   # pod 1 (x=2)
+        want = (g1 + g2) / 2
+        np.testing.assert_allclose(np.asarray(grads["w"]),
+                                   want * np.ones({n}), rtol=2e-2,
+                                   atol=1e-4)
+        np.testing.assert_allclose(float(loss), 0.5, rtol=1e-5)
+        assert new_err["w"].shape == (2, {n})
+        print("OK")
+    """)
+
+
 class TestGradCompression:
     def test_compressed_mean_close_and_error_feedback_accumulates(self):
-        run_with_devices("""
-            import jax, jax.numpy as jnp, numpy as np
-            from jax.sharding import PartitionSpec as P
-            from repro.train import grad_compress as gc
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                                 axis_types=(jax.sharding.AxisType.Auto,)*3)
+        _grad_compress_case(256)
 
-            def loss_and_grad(params, batch):
-                # per-pod quadratic: grads differ across pods via the batch
-                def loss(p):
-                    return jnp.mean((p["w"] * batch["x"] - 1.0) ** 2)
-                l, g = jax.value_and_grad(loss)(params)
-                return (l, {"ce": l, "aux": jnp.zeros(())}), g
-
-            f = gc.make_compressed_grads_fn(
-                loss_and_grad, mesh,
-                lambda leaf: P("pod", *([None] * (leaf.ndim - 1))))
-            params = {"w": jnp.ones((256,))}
-            batch = {"x": jnp.concatenate([jnp.ones((2, 256)),
-                                           2 * jnp.ones((2, 256))])}
-            err = gc.init_error_state(params, 2)
-            loss, metrics, grads, new_err = jax.jit(f)(params, batch, err)
-            # true mean-of-pod-grads: per pod, loss = mean over (2,256)
-            # elements; d/dw_i = (1/(2*256)) * sum_rows 2*(w_i*x-1)*x
-            g1 = 2 * 2 * (1.0 - 1.0) * 1.0 / 512     # pod 0 (x=1): 0
-            g2 = 2 * 2 * (2.0 - 1.0) * 2.0 / 512     # pod 1 (x=2)
-            want = (g1 + g2) / 2
-            np.testing.assert_allclose(np.asarray(grads["w"]),
-                                       want * np.ones(256), rtol=2e-2,
-                                       atol=1e-4)
-            np.testing.assert_allclose(float(loss), 0.5, rtol=1e-5)
-            assert new_err["w"].shape == (2, 256)
-            print("OK")
-        """)
+    def test_padded_leaf_quantizes_inside_manual_region(self):
+        # 250 is not a multiple of the 128-element quantization block, so
+        # the pad runs inside the pod-manual shard_map region
+        _grad_compress_case(250)
 
 
 class TestShardedTrainStep:

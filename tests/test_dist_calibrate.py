@@ -53,8 +53,13 @@ def test_shard_count_invariance_and_single_device_parity():
                                    devices=jax.devices()[:n],
                                    axis_types=(jax.sharding.AxisType.Auto,))
                   for n in (1, 4, 8)}
-        results = {n: calibrate_sharded(model, params, batches, m).r_factors()
+        sharded = {n: calibrate_sharded(model, params, batches, m)
                    for n, m in meshes.items()}
+        for n, m in meshes.items():
+            # each shard's R factors were built on that shard's own device
+            assert sharded[n].shard_devices == tuple(m.devices.reshape(-1)), \
+                (n, sharded[n].shard_devices)
+        results = {n: s.r_factors() for n, s in sharded.items()}
         for n, rf in results.items():
             assert set(rf) == set(single), (n, sorted(rf), sorted(single))
         # R is unique up to a left-orthogonal factor whose entrywise effect
@@ -113,7 +118,7 @@ def test_sharded_qr_beats_gram_when_ill_conditioned():
         from repro.core import baselines
         from repro.core.coala import coala_project
         from repro.core.tsqr import qr_r, square_r
-        from repro.dist.calibrate import combine_r_shards, split_batch
+        from repro.dist.calibrate import combine_r_shards
         n, k, rank, cond, shards = 32, 512, 6, 1e9, 8
         def rand(a, b, key):
             return jax.random.normal(jax.random.PRNGKey(key), (a, b),
@@ -151,3 +156,37 @@ def test_sharded_qr_beats_gram_when_ill_conditioned():
             (coala_err, v2_err)
         print("OK")
     """)
+
+
+def test_moe_expert_paths_match_single_device():
+    # a MoE expert's routed rows are not split like the batch: the capture
+    # routes the whole batch at once (as one device does) and zero-pads
+    # each record's rows to the shard count, so every expert path sees the
+    # single-device rows and token counts
+    run_with_devices("""
+        import jax, numpy as np
+        from repro.configs import get_smoke_config
+        from repro.core.calibrate import calibrate_model
+        from repro.data import DataConfig, TokenPipeline
+        from repro.dist.calibrate import calibrate_sharded
+        from repro.models import build_model
+        cfg = get_smoke_config("deepseek_moe_16b")
+        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+        pipe = TokenPipeline(DataConfig(vocab_size=cfg.vocab_size, seq_len=32,
+                                        global_batch=4, seed=3), cfg)
+        batches = [pipe.get_batch(i) for i in range(2)]
+        single = calibrate_model(model, params, batches)
+        mesh = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        sharded = calibrate_sharded(model, params, batches, mesh)
+        rs, rd = single.r_factors(), sharded.r_factors()
+        assert any("/expert" in p for p in rs), sorted(rs)
+        assert set(rd) == set(rs), sorted(set(rd) ^ set(rs))
+        assert sharded.tokens_seen() == single.tokens_seen()
+        for p, r in rs.items():
+            g = np.asarray(r, np.float64).T @ np.asarray(r, np.float64)
+            d = np.asarray(rd[p], np.float64)
+            rel = np.linalg.norm(d.T @ d - g) / np.linalg.norm(g)
+            assert rel < 1e-3, (p, rel)
+        print("OK")
+    """, n=4)

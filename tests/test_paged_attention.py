@@ -17,9 +17,9 @@ def _case(key, *, b, hq, hkv, hd, bs, num_blocks, lengths, dtype=jnp.float32):
     """Build a random pool + block tables covering ``lengths`` per request."""
     ks = jax.random.split(jax.random.PRNGKey(key), 3)
     q = jax.random.normal(ks[0], (b, hq, hd), jnp.float32).astype(dtype)
-    k_pages = jax.random.normal(ks[1], (num_blocks, bs, hkv, hd),
+    k_pages = jax.random.normal(ks[1], (num_blocks, hkv, bs, hd),
                                 jnp.float32).astype(dtype)
-    v_pages = jax.random.normal(ks[2], (num_blocks, bs, hkv, hd),
+    v_pages = jax.random.normal(ks[2], (num_blocks, hkv, bs, hd),
                                 jnp.float32).astype(dtype)
     # hand out distinct non-trash blocks round-robin; pad rows with block 0
     nb = max(-(-max(lengths, default=1) // bs), 1)
@@ -38,8 +38,9 @@ def _dense_oracle(q, k_pages, v_pages, tables, lengths, *, scale=None,
                   cap=0.0, window=0):
     """Per-request contiguous softmax attention in fp64."""
     q = np.asarray(q, np.float64)
-    kp = np.asarray(k_pages, np.float64)
-    vp = np.asarray(v_pages, np.float64)
+    # head-major pages -> token-major (num_blocks, bs, hkv, hd)
+    kp = np.asarray(k_pages, np.float64).transpose(0, 2, 1, 3)
+    vp = np.asarray(v_pages, np.float64).transpose(0, 2, 1, 3)
     tables = np.asarray(tables)
     lengths = np.asarray(lengths)
     b, hq, hd = q.shape

@@ -106,6 +106,20 @@ class TestBlockPool:
             if sp.token_axis is not None:
                 assert float(jnp.abs(g).max()) == 0.0
 
+    def test_token_store_is_head_major(self, smollm):
+        """A stacked K/V leaf (n_rep, B, T, Hkv, hd) is stored as
+        (n_rep, num_blocks, Hkv, block_size, hd): one page of one head is
+        a whole (block_size, hd) tile, the block the TPU kernels read."""
+        cfg, model, _ = smollm
+        pool = _pool(model)
+        for sp, store in zip([s for s in pool.layout.specs
+                              if s.token_axis is not None],
+                             pool.token_store):
+            assert store.shape[-3:] == (cfg.n_kv_heads, pool.block_size,
+                                         cfg.head_dim)
+            assert store.shape[sp.blocks_axis] == pool.num_blocks
+            assert sp.in_page_axis == store.ndim - 2
+
     def test_layout_probe_families(self):
         """Probe classifies token-axis vs per-request-state leaves across
         decoder-only, enc-dec, and recurrent cache layouts."""
@@ -279,6 +293,20 @@ class TestContinuousEngine:
         _, cont = _engines(model, params, block_size=4, num_blocks=2)
         with pytest.raises(ValueError, match="blocks"):
             cont.submit(np.zeros((16,), np.int32), 4)
+
+    def test_nonfinite_logits_are_flagged(self, smollm):
+        """Each request records whether every logit it sampled from was
+        finite: true for sane weights, false once the output head is NaN."""
+        cfg, model, params = smollm
+        prompt = np.arange(5, dtype=np.int32)
+        _, cont = _engines(model, params)
+        cont.submit(prompt, 3)
+        assert all(r.logits_finite for r in cont.run())
+        bad = jax.tree.map(lambda a: a, params)
+        bad["embed"] = bad["embed"].at[:, 0].set(jnp.nan)
+        _, cont = _engines(model, bad)
+        cont.submit(prompt, 3)
+        assert not any(r.logits_finite for r in cont.run())
 
     def test_per_request_temperature(self, smollm):
         """Greedy and sampled requests coexist in one batch; the greedy row
