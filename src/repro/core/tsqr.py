@@ -26,6 +26,8 @@ from typing import Iterable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace
+
 
 def _fix_sign(r: jax.Array) -> jax.Array:
     """Flip row signs so diag(R) >= 0 (makes R unique for full-rank input)."""
@@ -70,6 +72,16 @@ def tsqr_tree(chunks: Sequence[jax.Array]) -> jax.Array:
     return rs[0]
 
 
+def calib_fold_first(chunk: jax.Array) -> jax.Array:
+    """R of a stream's first chunk (jitted, the program ``calib_fold_first``)."""
+    return qr_r(chunk)
+
+
+def calib_fold(r: jax.Array, chunk: jax.Array) -> jax.Array:
+    """Fold a chunk into a stream's R (jitted, the program ``calib_fold``)."""
+    return stack_qr(r, chunk)
+
+
 class RStreamer:
     """Stateful streaming R accumulator used by the calibration pipeline.
 
@@ -82,13 +94,16 @@ class RStreamer:
         self.dtype = dtype
         self._r: Optional[jax.Array] = None
         self.tokens_seen = 0
-        self._update = jax.jit(stack_qr)
-        self._first = jax.jit(qr_r)
+        self._update = jax.jit(calib_fold)
+        self._first = jax.jit(calib_fold_first)
 
     def update(self, chunk: jax.Array) -> None:
         chunk = chunk.reshape(-1, self.n).astype(self.dtype)
-        self.tokens_seen += int(chunk.shape[0])
-        self._r = self._first(chunk) if self._r is None else self._update(self._r, chunk)
+        rows = int(chunk.shape[0])
+        self.tokens_seen += rows
+        with trace.span("calib.fold", rows=rows, n=self.n):
+            self._r = (self._first(chunk) if self._r is None
+                       else self._update(self._r, chunk))
 
     @property
     def r(self) -> jax.Array:

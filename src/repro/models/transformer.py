@@ -27,6 +27,7 @@ from repro.models.common import (CPU_CTX, ParallelCtx, constrain_act, make_norm,
                                  mrope_cos_sin, rope_cos_sin, softcap,
                                  dense_init, split_key)
 from repro.models.linear import linear_apply
+from repro.obs import trace
 
 
 def chunked_ce(h, targets, head_w, *, transform=None, chunk: int = 512):
@@ -397,10 +398,14 @@ class LM:
     def capture_forward(self, params, batch, calibrator, *,
                         ctx: ParallelCtx = CPU_CTX, compute_dtype=jnp.float32):
         """Unrolled-eager forward that streams every target linear's input
-        activations into the calibrator (per-layer R factors, never X)."""
-        x = self._embed(params, batch["tokens"], batch.get("vision_embeds"))
-        x = x.astype(compute_dtype)
-        h, _, _ = self._backbone(params, x, ctx=ctx, capture=calibrator)
+        activations into the calibrator (per-layer R factors, never X).
+        Its ``calib.capture`` span holds the eager forward's dispatch and
+        that of every fold it triggers."""
+        with trace.span("calib.capture"):
+            x = self._embed(params, batch["tokens"],
+                            batch.get("vision_embeds"))
+            x = x.astype(compute_dtype)
+            h, _, _ = self._backbone(params, x, ctx=ctx, capture=calibrator)
         return h
 
     def capture_prefill(self, params, tokens, calibrator, *,

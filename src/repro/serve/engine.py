@@ -405,46 +405,56 @@ class ContinuousEngine:
         reg.gauge("serve_post_warmup_compiles",
                   "decode+prefill jit compiles not covered by warmup()",
                   fn=self.post_warmup_compiles)
+        # every jitted program is a named def: its name is the XLA module's
+        # (``jit_serve_decode_paged``), which the device trace shows
         m, cd = model, compute_dtype
-        self._prefill = jax.jit(
-            lambda p, tk, c, **kw: m.prefill(p, tk, c, ctx=ctx,
-                                             compute_dtype=cd, **kw))
-        self._decode = jax.jit(
-            lambda p, tk, c, pos: m.decode_step(p, tk, c, pos, ctx=ctx,
-                                                compute_dtype=cd))
+
+        def serve_prefill(p, tk, c, **kw):
+            return m.prefill(p, tk, c, ctx=ctx, compute_dtype=cd, **kw)
+
+        def serve_decode(p, tk, c, pos):
+            return m.decode_step(p, tk, c, pos, ctx=ctx, compute_dtype=cd)
+
+        def serve_decode_paged(p, tk, c, pos, bt):
+            return m.decode_step(p, tk, c, pos, ctx=ctx, compute_dtype=cd,
+                                 block_tables=bt)
+
+        def serve_prefill_chunk(p, tk, c, pos, lens):
+            return m.prefill_chunk(p, tk, c, pos, lens, ctx=ctx,
+                                   compute_dtype=cd)
+
+        def serve_prefill_paged(p, tk, c, pos, lens, bt):
+            return m.prefill_chunk(p, tk, c, pos, lens, ctx=ctx,
+                                   compute_dtype=cd, block_tables=bt)
+
+        def serve_sample(lg, t, k):
+            # sampled tokens plus, per row, whether every logit was finite
+            return _sample_rows(lg, t, k), jnp.all(jnp.isfinite(lg), axis=-1)
+
+        self._prefill = jax.jit(serve_prefill)
+        self._decode = jax.jit(serve_decode)
         # page stores are donated so XLA writes the new token in place
         # instead of copying every page each step
-        self._decode_paged = jax.jit(
-            lambda p, tk, c, pos, bt: m.decode_step(
-                p, tk, c, pos, ctx=ctx, compute_dtype=cd, block_tables=bt),
-            donate_argnums=(2,))
+        self._decode_paged = jax.jit(serve_decode_paged, donate_argnums=(2,))
+        self._prefill_chunk = None
+        self._prefill_chunk_paged = None
         if chunk_ok:
             # the gathered suffix-prefill cache is the largest transient in
             # the serving path; donate it so XLA updates it in place instead
             # of holding input + output copies alive
-            self._prefill_chunk = jax.jit(
-                lambda p, tk, c, pos, lens: m.prefill_chunk(
-                    p, tk, c, pos, lens, ctx=ctx, compute_dtype=cd),
-                donate_argnums=(2,))
-        else:
-            self._prefill_chunk = None
+            self._prefill_chunk = jax.jit(serve_prefill_chunk,
+                                          donate_argnums=(2,))
         if self.prefill_kernel:
             # page stores donated, like decode: the suffix K/V scatter and
             # the chunked-prefill kernel update the pages in place
-            self._prefill_chunk_paged = jax.jit(
-                lambda p, tk, c, pos, lens, bt: m.prefill_chunk(
-                    p, tk, c, pos, lens, ctx=ctx, compute_dtype=cd,
-                    block_tables=bt),
-                donate_argnums=(2,))
-        else:
-            self._prefill_chunk_paged = None
-        # sampled tokens plus, per row, whether every logit was finite
-        self._sample = jax.jit(lambda lg, t, k: (
-            _sample_rows(lg, t, k), jnp.all(jnp.isfinite(lg), axis=-1)))
+            self._prefill_chunk_paged = jax.jit(serve_prefill_paged,
+                                                donate_argnums=(2,))
+        self._sample = jax.jit(serve_sample)
         if self._spec:
             spec_steps = self.spec_k + 1
 
-            def _draft_scan(p, tok, cache, pos, bt, temps, seeds, offs):
+            def serve_spec_draft(p, tok, cache, pos, bt, temps, seeds,
+                                 offs):
                 # ONE dispatch proposes the whole k-token draft run: the
                 # scan feeds the last committed token then each proposal
                 # back in, sampling in-scan (keys derived in-graph from the
@@ -469,16 +479,17 @@ class ContinuousEngine:
                     body, (tok, pos, cache), jnp.arange(spec_steps))
                 return props, logits, cache
 
-            self._spec_draft = jax.jit(_draft_scan, donate_argnums=(2,))
+            self._spec_draft = jax.jit(serve_spec_draft,
+                                       donate_argnums=(2,))
 
-            def _verify_fn(p, tk, c, pos, lens, bt):
+            def serve_verify(p, tk, c, pos, lens, bt):
                 logits, c = m.verify_chunk(p, tk, c, pos, lens, ctx=ctx,
                                            compute_dtype=cd, block_tables=bt)
                 # greedy argmax computed in-graph so greedy rounds transfer
                 # (B, k+1) ints, not (B, k+1, vocab) logits
                 return logits, jnp.argmax(logits, -1).astype(jnp.int32), c
 
-            self._verify = jax.jit(_verify_fn, donate_argnums=(2,))
+            self._verify = jax.jit(serve_verify, donate_argnums=(2,))
         else:
             self._spec_draft = None
             self._verify = None
@@ -557,34 +568,18 @@ class ContinuousEngine:
             # always lands on a step boundary, never mid-dispatch
             self._recalib.on_step(self)
         done: List[Request] = []
-        admitted = self.scheduler.admit()
+        admitted: List[Request] = []
         groups: Dict[int, list] = {}
-        for req in admitted:
-            if not req.cacheable:
-                self._prefill_request(req)            # extras / hybrid archs
-                continue
-            # allocate (and thereby look up the cached prefix) once; the
-            # suffix length both picks the batch group and feeds the prefill
-            toks = req.prefill_tokens()
-            cached = self.pool.alloc(req.req_id, len(toks), tokens=toks)
-            if self._spec:
-                # lockstep pools: the mirrored call sequence keeps the draft
-                # registry identical, so hits (and suffix shapes) match
-                dcached = self.draft_pool.alloc(req.req_id, len(toks),
-                                                tokens=toks)
-                assert dcached == cached, "draft pool diverged from target"
-            self._c_prompt_tokens.inc(len(toks))
-            self._c_prefix_hit_tokens.inc(cached)
-            if self.flight is not None and cached:
-                self.flight.record("prefix_hit", req_id=req.req_id,
-                                   cached_tokens=int(cached))
-            if self._recalib is not None:
-                # capture rides the admission path: the recalibrator replays
-                # exactly the tokens this prefill is about to compute over
-                self._recalib.on_prefill(self, req)
-            groups.setdefault(
-                self._bucket_prefill(len(toks) - cached),
-                []).append((req, toks, cached))
+        if self.scheduler.waiting:
+            # nothing waits at steady state: no admit span on most steps
+            with trace.span("serve.admit") as sp:
+                admitted = self.scheduler.admit()
+                for req in admitted:
+                    if req.cacheable:
+                        self._alloc_admitted(req, groups)
+                    else:
+                        self._prefill_request(req)  # extras / hybrid archs
+                sp.set(admitted=len(admitted))
         for _, group in sorted(groups.items()):
             self._prefill_batch(group)
         for req in admitted:
@@ -596,6 +591,30 @@ class ContinuousEngine:
             done.extend(self._spec_decode_step(running) if self._spec
                         else self._decode_step(running))
         return done
+
+    def _alloc_admitted(self, req: Request, groups: Dict[int, list]) -> None:
+        """Allocate an admitted request's pages (and thereby look up its
+        cached prefix) once; the suffix length both picks the request's
+        prefill batch group and feeds the prefill."""
+        toks = req.prefill_tokens()
+        cached = self.pool.alloc(req.req_id, len(toks), tokens=toks)
+        if self._spec:
+            # lockstep pools: the mirrored call sequence keeps the draft
+            # registry identical, so hits (and suffix shapes) match
+            dcached = self.draft_pool.alloc(req.req_id, len(toks),
+                                            tokens=toks)
+            assert dcached == cached, "draft pool diverged from target"
+        self._c_prompt_tokens.inc(len(toks))
+        self._c_prefix_hit_tokens.inc(cached)
+        if self.flight is not None and cached:
+            self.flight.record("prefix_hit", req_id=req.req_id,
+                               cached_tokens=int(cached))
+        if self._recalib is not None:
+            # capture rides the admission path: the recalibrator replays
+            # exactly the tokens this prefill is about to compute over
+            self._recalib.on_prefill(self, req)
+        groups.setdefault(self._bucket_prefill(len(toks) - cached),
+                          []).append((req, toks, cached))
 
     def fork(self, req_id: int, *, temperature: Optional[float] = None,
              seed: Optional[int] = None) -> int:
@@ -1242,16 +1261,18 @@ class ContinuousEngine:
         (sampled greedily on garbage logits, discarded by the caller). A
         request whose logits held a NaN or inf is marked
         ``logits_finite = False``."""
-        pad = max(pad_to - len(reqs), 0)
-        temps = jnp.asarray([r.temperature for r in reqs] + [0.0] * pad,
-                            jnp.float32)
-        keys = jnp.stack([
-            jax.random.fold_in(jax.random.PRNGKey(r.seed), len(r.out_tokens))
-            for r in reqs] + [jax.random.PRNGKey(0)] * pad)
-        toks, finite = self._sample(logits, temps, keys)
-        for r, ok in zip(reqs, np.asarray(finite)):
-            r.logits_finite &= bool(ok)
-        return np.asarray(toks)[:len(reqs)]
+        with trace.span("serve.sample", rows=len(reqs)):
+            pad = max(pad_to - len(reqs), 0)
+            temps = jnp.asarray([r.temperature for r in reqs] + [0.0] * pad,
+                                jnp.float32)
+            keys = jnp.stack([
+                jax.random.fold_in(jax.random.PRNGKey(r.seed),
+                                   len(r.out_tokens))
+                for r in reqs] + [jax.random.PRNGKey(0)] * pad)
+            toks, finite = self._sample(logits, temps, keys)
+            for r, ok in zip(reqs, np.asarray(finite)):
+                r.logits_finite &= bool(ok)
+            return np.asarray(toks)[:len(reqs)]
 
     def _prefill_request(self, req: Request) -> None:
         with trace.span("serve.prefill_request", req_id=req.req_id,
@@ -1292,37 +1313,39 @@ class ContinuousEngine:
         (``kernels/chunked_prefill.py``); the donated stores flow back via
         ``absorb_paged`` — no gather/scatter of the cache. The gather path
         stays as the in-tree oracle."""
-        reqs = [r for r, _, _ in group]
-        ids = [r.req_id for r in reqs]
-        starts = [cached for _, _, cached in group]
-        suffixes = [np.asarray(toks[cached:], np.int32)
-                    for _, toks, cached in group]
-        lens = [len(s) for s in suffixes]
-        l_pad = self._bucket_prefill(max(lens))
-        b_pad = self._bucket_batch(len(group))
-        nb_pad = _pow2_at_least(max(self.pool.blocks_for(s + l_pad)
-                                    for s in starts))
-        sig = (b_pad, l_pad, nb_pad)
-        if self.flight is not None:
-            for r, ln_i in zip(reqs, lens):
-                self.flight.record("prefill", req_id=r.req_id,
-                                   suffix_tokens=int(ln_i), bucket=l_pad,
-                                   batch=len(group))
-        fresh = sig not in self._prefill_shapes or (
-            self._spec and sig not in self._draft_prefill_shapes)
-        self._prefill_shapes.add(sig)
-        if self._spec:
-            self._draft_prefill_shapes.add(sig)
-        if fresh:
-            trace.instant("serve.prefill_compile", sig=str(sig))
-        tok = np.zeros((b_pad, l_pad), np.int32)
-        for i, s in enumerate(suffixes):
-            tok[i, :len(s)] = s
-        pos = jnp.asarray(starts + [0] * (b_pad - len(group)), jnp.int32)
-        ln = jnp.asarray(lens + [1] * (b_pad - len(group)), jnp.int32)
+        with trace.span("serve.prepare"):
+            reqs = [r for r, _, _ in group]
+            ids = [r.req_id for r in reqs]
+            starts = [cached for _, _, cached in group]
+            suffixes = [np.asarray(toks[cached:], np.int32)
+                        for _, toks, cached in group]
+            lens = [len(s) for s in suffixes]
+            l_pad = self._bucket_prefill(max(lens))
+            b_pad = self._bucket_batch(len(group))
+            nb_pad = _pow2_at_least(max(self.pool.blocks_for(s + l_pad)
+                                        for s in starts))
+            sig = (b_pad, l_pad, nb_pad)
+            if self.flight is not None:
+                for r, ln_i in zip(reqs, lens):
+                    self.flight.record("prefill", req_id=r.req_id,
+                                       suffix_tokens=int(ln_i), bucket=l_pad,
+                                       batch=len(group))
+            fresh = sig not in self._prefill_shapes or (
+                self._spec and sig not in self._draft_prefill_shapes)
+            self._prefill_shapes.add(sig)
+            if self._spec:
+                self._draft_prefill_shapes.add(sig)
+            if fresh:
+                trace.instant("serve.prefill_compile", sig=sig)
+            tok = np.zeros((b_pad, l_pad), np.int32)
+            for i, s in enumerate(suffixes):
+                tok[i, :len(s)] = s
+            pos = jnp.asarray(starts + [0] * (b_pad - len(group)), jnp.int32)
+            ln = jnp.asarray(lens + [1] * (b_pad - len(group)), jnp.int32)
         t0 = time.perf_counter()
-        with trace.span("serve.prefill_batch", batch=len(group),
-                        tokens=sum(lens), sig=str(sig)):
+        with trace.span("serve.prefill_batch", rows=len(group),
+                        padded_rows=b_pad, tokens=sum(lens),
+                        padded_tokens=b_pad * l_pad, sig=sig):
             if self.prefill_kernel:
                 tables = self.pool.padded_tables(ids, rows=b_pad,
                                                  blocks=nb_pad)
@@ -1368,54 +1391,62 @@ class ContinuousEngine:
             self._c_prefill_tokens.inc(sum(lens))
         self._c_prefill_batches.inc()
         nxt = self._sample_tokens(logits, reqs, pad_to=b_pad)
-        now = time.perf_counter()
-        for r, start, ln_i, t in zip(reqs, starts, lens, nxt):
-            r.cache_len = start + ln_i
-            r.out_tokens.append(int(t))
-            self._emit_stream(r, int(t), r.done)
-            if r.first_token_time is None:
-                r.first_token_time = now
-                self._h_ttft.observe(r.ttft)
-                if self.flight is not None:
-                    self.flight.record("first_token", req_id=r.req_id,
-                                       ttft_s=r.ttft)
-            self.pool.commit(r.req_id, r.prefill_tokens()[:r.cache_len])
-            if self._spec:
-                self.draft_pool.commit(r.req_id,
-                                       r.prefill_tokens()[:r.cache_len])
+        with trace.span("serve.emit") as sp:
+            now = time.perf_counter()
+            finished = 0
+            for r, start, ln_i, t in zip(reqs, starts, lens, nxt):
+                r.cache_len = start + ln_i
+                r.out_tokens.append(int(t))
+                fin = bool(r.done)
+                finished += fin
+                self._emit_stream(r, int(t), fin)
+                if r.first_token_time is None:
+                    r.first_token_time = now
+                    self._h_ttft.observe(r.ttft)
+                    if self.flight is not None:
+                        self.flight.record("first_token", req_id=r.req_id,
+                                           ttft_s=r.ttft)
+                self.pool.commit(r.req_id, r.prefill_tokens()[:r.cache_len])
+                if self._spec:
+                    self.draft_pool.commit(r.req_id,
+                                           r.prefill_tokens()[:r.cache_len])
+            # a request done at its first token is finished by step()
+            sp.set(finished=finished)
 
     def _decode_step(self, running: List[Request]) -> List[Request]:
-        # reserve the next position for everyone, preempting the youngest
-        # request when the pool runs dry
-        while True:
-            try:
-                for r in running:
-                    self.pool.extend(r.req_id, r.cache_len + 1)
-                break
-            except MemoryError:
-                victim = self.scheduler.preempt_youngest()
-                running = [r for r in running if r is not victim]
-                if not running:
-                    raise MemoryError(
-                        "block pool too small for a single request")
-        ids = [r.req_id for r in running]
-        b_real = len(ids)
-        # bucket the (batch, blocks) envelope to a closed signature set;
-        # padding rows carry pos 0 and all-trash tables/slots
-        b_pad = self._bucket_batch(b_real)
-        nb_pad = _pow2_at_least(self.pool.max_table_blocks(ids))
-        sig = (b_pad, nb_pad, self.paged_kernel)
-        fresh = sig not in self._decode_shapes
-        self._decode_shapes.add(sig)
-        if fresh:
-            trace.instant("serve.decode_compile", sig=str(sig))
-        tables = self.pool.padded_tables(ids, rows=b_pad, blocks=nb_pad)
-        tok = jnp.asarray([[r.out_tokens[-1]] for r in running]
-                          + [[0]] * (b_pad - b_real), jnp.int32)
-        pos = jnp.asarray([r.cache_len for r in running]
-                          + [0] * (b_pad - b_real), jnp.int32)
+        with trace.span("serve.prepare"):
+            # reserve the next position for everyone, preempting the
+            # youngest request when the pool runs dry
+            while True:
+                try:
+                    for r in running:
+                        self.pool.extend(r.req_id, r.cache_len + 1)
+                    break
+                except MemoryError:
+                    victim = self.scheduler.preempt_youngest()
+                    running = [r for r in running if r is not victim]
+                    if not running:
+                        raise MemoryError(
+                            "block pool too small for a single request")
+            ids = [r.req_id for r in running]
+            b_real = len(ids)
+            # bucket the (batch, blocks) envelope to a closed signature set;
+            # padding rows carry pos 0 and all-trash tables/slots
+            b_pad = self._bucket_batch(b_real)
+            nb_pad = _pow2_at_least(self.pool.max_table_blocks(ids))
+            sig = (b_pad, nb_pad, self.paged_kernel)
+            fresh = sig not in self._decode_shapes
+            self._decode_shapes.add(sig)
+            if fresh:
+                trace.instant("serve.decode_compile", sig=sig)
+            tables = self.pool.padded_tables(ids, rows=b_pad, blocks=nb_pad)
+            tok = jnp.asarray([[r.out_tokens[-1]] for r in running]
+                              + [[0]] * (b_pad - b_real), jnp.int32)
+            pos = jnp.asarray([r.cache_len for r in running]
+                              + [0] * (b_pad - b_real), jnp.int32)
         t0 = time.perf_counter()
-        with trace.span("serve.decode_step", batch=b_real, sig=str(sig)):
+        with trace.span("serve.decode_step", rows=b_real, padded_rows=b_pad,
+                        sig=sig):
             if self.paged_kernel:
                 cache = self.pool.paged_cache(ids, rows=b_pad)
                 logits, cache = self._decode_paged(self.params, tok, cache,
@@ -1437,17 +1468,21 @@ class ContinuousEngine:
             r.cache_len += 1
         nxt = self._sample_tokens(logits, running, pad_to=b_pad)
         done = []
-        for r, t in zip(running, nxt):
-            r.out_tokens.append(int(t))
-            self._emit_stream(r, int(t), r.done)
-            if (self.prefix_cache and r.cacheable
-                    and r.cache_len % self.block_size == 0):
-                # a generated block just filled: register it so identical
-                # traffic (and this request, if preempted) can reuse it
-                self.pool.commit(r.req_id, r.prefill_tokens()[:r.cache_len])
-            if r.done:
-                self._finish(r)
-                done.append(r)
+        with trace.span("serve.emit") as sp:
+            for r, t in zip(running, nxt):
+                r.out_tokens.append(int(t))
+                self._emit_stream(r, int(t), r.done)
+                if (self.prefix_cache and r.cacheable
+                        and r.cache_len % self.block_size == 0):
+                    # a generated block just filled: register it so
+                    # identical traffic (and this request, if preempted)
+                    # can reuse it
+                    self.pool.commit(r.req_id,
+                                     r.prefill_tokens()[:r.cache_len])
+                if r.done:
+                    self._finish(r)
+                    done.append(r)
+            sp.set(finished=len(done))
         return done
 
     def _spec_decode_step(self, running: List[Request]) -> List[Request]:
@@ -1493,7 +1528,7 @@ class ContinuousEngine:
         fresh = sig not in self._spec_shapes
         self._spec_shapes.add(sig)
         if fresh:
-            trace.instant("serve.spec_compile", sig=str(sig))
+            trace.instant("serve.spec_compile", sig=sig)
         pad = b_pad - b_real
         tok = jnp.asarray([[r.out_tokens[-1]] for r in running]
                           + [[0]] * pad, jnp.int32)
@@ -1507,7 +1542,7 @@ class ContinuousEngine:
                            jnp.int32)
         starts = [r.cache_len for r in running]
         t0 = time.perf_counter()
-        with trace.span("serve.spec_step", batch=b_real, sig=str(sig)):
+        with trace.span("serve.spec_step", batch=b_real, sig=sig):
             with trace.span("serve.spec_draft", batch=b_real):
                 # the draft always runs on the gathered contiguous envelope:
                 # one pool read before the scan, one suffix write-back after,

@@ -138,38 +138,33 @@ class Scheduler:
         admit() batch never promises the same blocks twice."""
         admitted = []
         reserved = 0
-        if not self.waiting:
-            # nothing to admit: skip the span too — at steady state this
-            # is every step, and an empty admit span per decode step is
-            # pure tracing overhead (the obs_overhead_pct bar is tight)
-            return admitted
-        with trace.span("serve.admit", waiting=len(self.waiting),
-                        running=len(self.running)):
-            # prefix-cached blocks in the LRU are evictable on demand, so
-            # they count as admissible capacity (a hit needs even less)
-            avail = getattr(self.pool, "available_blocks",
-                            self.pool.free_blocks)
-            while self.waiting and len(self.running) < self.max_running:
-                req = self.waiting[0]
-                need = self.pool.blocks_for(req.cache_budget()
-                                            + self.headroom_tokens)
-                if (need + reserved > avail
-                        or len(admitted) + 1 > self.pool.free_slots):
-                    break
-                reserved += need
-                self.waiting.popleft()
-                req.state = RUNNING
-                req.admit_seq = self._admit_seq
-                self._admit_seq += 1
-                self.running.append(req)
-                admitted.append(req)
-                self._c_admitted.inc()
-                wait = time.perf_counter() - req.arrival_time
-                self._h_queue_wait.observe(wait)
-                if self.flight is not None:
-                    self.flight.record("admit", req_id=req.req_id,
-                                       queue_wait_s=wait, blocks=need,
-                                       preemptions=req.preemptions)
+        # prefix-cached blocks in the LRU are evictable on demand, so they
+        # count as admissible capacity (a hit needs even less)
+        avail = getattr(self.pool, "available_blocks", self.pool.free_blocks)
+        while self.waiting and len(self.running) < self.max_running:
+            req = self.waiting[0]
+            need = self.pool.blocks_for(req.cache_budget()
+                                        + self.headroom_tokens)
+            if (need + reserved > avail
+                    or len(admitted) + 1 > self.pool.free_slots):
+                break
+            reserved += need
+            self.waiting.popleft()
+            req.state = RUNNING
+            req.admit_seq = self._admit_seq
+            self._admit_seq += 1
+            self.running.append(req)
+            admitted.append(req)
+            self._c_admitted.inc()
+            now = time.perf_counter()
+            wait = now - req.arrival_time
+            self._h_queue_wait.observe(wait)
+            trace.complete("serve.queue_wait", req.arrival_time, now,
+                           req_id=req.req_id)
+            if self.flight is not None:
+                self.flight.record("admit", req_id=req.req_id,
+                                   queue_wait_s=wait, blocks=need,
+                                   preemptions=req.preemptions)
         return admitted
 
     def adopt(self, req: Request) -> None:

@@ -32,7 +32,6 @@ def _artifact():
         ("serve/warmup_seconds", "12.31"),
         ("serve/post_warmup_compiles", 0),
         ("serve/offline_tok_per_s", "95.30"),
-        ("serve/obs_overhead_pct", "1.25"),
         ("serve/slo_goodput", "1.0"),
         ("serve/serve_tpot_seconds_p50", "0.012"),
         ("serve/serve_tpot_seconds_p99", "0.019"),
@@ -103,7 +102,6 @@ def test_band_override_tightens(gate):
 
 @pytest.mark.parametrize("name,value,frag", [
     ("serve/post_warmup_compiles", 3, "hard invariant"),
-    ("serve/obs_overhead_pct", "7.5", "hard invariant"),
     ("serve/slo_goodput", "0.75", "hard invariant"),
     ("serve/paged_vs_gather_decode_speedup", "0.90", "hard invariant"),
     ("serve/spec_decode_speedup", "0.95", "hard invariant"),

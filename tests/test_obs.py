@@ -18,6 +18,7 @@ Covers the PR-6 contracts:
 """
 import json
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -154,6 +155,94 @@ class TestTracer:
         assert t.dropped == 5
         t.instant("e8")
         assert [e["name"] for e in t.events()] == ["e6", "e7", "e8"]
+
+
+def _profile_host_events(logdir):
+    """(name, stats) of every event on the profiler trace's host planes."""
+    import glob
+    found = glob.glob(str(logdir / "**" / "*.xplane.pb"), recursive=True)
+    assert found, f"no .xplane.pb under {logdir}"
+    pd = jax.profiler.ProfileData.from_file(found[0])
+    return [(e.name, dict(e.stats)) for plane in pd.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+class TestProfilerSpans:
+    """Spans follow a ``jax.profiler`` session onto its host plane and into
+    ``trace.spans``, with nothing recorded while neither is on."""
+
+    def test_span_reaches_profiler_and_spans(self, tmp_path):
+        lo = time.perf_counter()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("outer", rows=3) as sp:
+                with trace.span("inner", sig=(4, 8, True)):
+                    jnp.ones(4).block_until_ready()
+                sp.set(finished=1)
+        finally:
+            jax.profiler.stop_trace()
+        hi = time.perf_counter()
+        assert not trace.enabled()          # recorded in the profiler's ring
+        got = {s.name: s for s in trace.spans(lo, hi)}
+        assert set(got) == {"outer", "inner"}
+        out, inn = got["outer"], got["inner"]
+        assert inn.parent == out.id and out.parent is None
+        assert out.start <= inn.start <= inn.end <= out.end
+        assert out.args == {"rows": 3, "finished": 1}
+        assert inn.args == {"sig": (4, 8, True)}
+        host = dict(_profile_host_events(tmp_path))
+        assert host["outer"] == {"rows": 3, "finished": 1}
+        assert host["inner"] == {"sig": "(4, 8, True)"}
+        # once the session ends, spans are free again
+        assert trace.span("after") is trace.span("after2")
+        assert [s.name for s in trace.spans(lo, time.perf_counter())] == \
+            ["inner", "outer"]
+
+    def test_off_records_nothing(self):
+        lo = time.perf_counter()
+        with trace.span("a", rows=1) as sp:
+            sp.set(finished=2)
+        trace.complete("serve.queue_wait", lo, req_id=1)
+        trace.instant("tick")
+        assert trace.span("b") is trace.span("c")
+        assert trace.spans(lo, time.perf_counter()) == []
+        assert trace.current() is None
+
+    def test_complete_event_and_raw_args_export(self, tmp_path):
+        t = trace.enable()
+        start = time.perf_counter()
+        with trace.span("serve.decode_step", rows=2, sig=(2, 4, True)):
+            pass
+        trace.complete("serve.queue_wait", start, start + 0.25, req_id=7)
+        q = [s for s in trace.spans(start, start + 1.0)
+             if s.name == "serve.queue_wait"]
+        assert len(q) == 1 and q[0].end - q[0].start == 0.25
+        assert q[0].parent is None and q[0].args == {"req_id": 7}
+        # an interval may overlap others on its thread: it is exported as
+        # an async begin/end pair, not as a complete event
+        path = tmp_path / "t.json"
+        assert trace.save(str(path)) == 3
+        evs = {(e["name"], e["ph"]): e for e in json.loads(
+            path.read_text())["traceEvents"]}
+        assert evs["serve.decode_step", "X"]["args"] == {
+            "rows": 2, "sig": "(2, 4, True)"}
+        b, e = evs["serve.queue_wait", "b"], evs["serve.queue_wait", "e"]
+        assert b["id"] == e["id"] and b["args"] == {"req_id": 7}
+        assert b["ts"] == pytest.approx((start - t._t0) * 1e6)
+        assert e["ts"] - b["ts"] == pytest.approx(2.5e5)
+
+    def test_window_past_a_dropped_event_is_unreadable(self):
+        trace.enable(max_events=4)
+        marks = []
+        for i in range(10):
+            marks.append(time.perf_counter())
+            with trace.span(f"s{i}"):
+                pass
+        assert trace.current().dropped == 6
+        assert trace.spans(marks[0], marks[-1]) is None
+        late = trace.spans(marks[7], time.perf_counter())
+        assert [s.name for s in late] == ["s7", "s8", "s9"]
 
 
 def _nesting_ok(events):
@@ -440,8 +529,9 @@ class TestEngineWiring:
         assert {"serve.admit", "serve.prefill_batch",
                 "serve.decode_step"} <= names
         assert "serve.decode_compile" in names    # instant events
+        assert "serve.queue_wait" in names        # async intervals
         for e in evs:
-            assert e["ph"] in ("X", "i", "M")
+            assert e["ph"] in ("X", "i", "M", "b", "e")
             if e["ph"] == "X":
                 assert e["dur"] >= 0 and "tid" in e
         assert _nesting_ok(evs)
@@ -454,6 +544,156 @@ class TestEngineWiring:
             eng.step()
         assert not trace.enabled()
         assert trace.save("/tmp/unused.json") == 0
+
+
+STEP_LEAVES = {"serve.admit", "serve.prepare", "serve.prefill_batch",
+               "serve.decode_step", "serve.sample", "serve.emit"}
+
+
+def _covered(intervals):
+    total, end = 0.0, float("-inf")
+    for s, e in sorted(intervals):
+        if e > end:
+            total += e - max(s, end)
+            end = e
+    return total
+
+
+class TestEngineSpans:
+    """The engine's spans divide each ``step()`` into its parts, with the
+    counts of what it dispatched, and its jitted programs carry names."""
+
+    def _serve(self, eng, cfg, n=3):
+        for i in range(n):
+            eng.submit(_prompt(cfg, 5 + 3 * i, seed=i), 6)
+        walls = []
+        while eng.has_work():
+            t0 = time.perf_counter()
+            eng.step()
+            walls.append((t0, time.perf_counter()))
+        return walls
+
+    def test_leaf_spans_cover_each_step(self, smollm):
+        cfg, model, params = smollm
+        eng = _engine(model, params)
+        trace.enable()
+        walls = self._serve(eng, cfg)
+        assert len(walls) >= 5
+        for lo, hi in walls:
+            spans = trace.spans(lo, hi)
+            parents = {s.parent for s in spans}
+            leaves = [(max(s.start, lo), min(s.end, hi)) for s in spans
+                      if s.name in STEP_LEAVES and s.id not in parents]
+            assert {"serve.decode_step", "serve.sample",
+                    "serve.emit"} <= {s.name for s in spans}
+            assert _covered(leaves) >= 0.9 * (hi - lo)
+
+    def test_span_counts_match_what_was_dispatched(self, smollm):
+        cfg, model, params = smollm
+        eng = _engine(model, params, max_running=3)
+        calls = {"decode": [], "prefill": []}
+
+        def spy(kind, fn):
+            def call(p, tok, *rest):
+                lens = rest[2] if kind == "prefill" else None
+                calls[kind].append((tok.shape, lens))
+                return fn(p, tok, *rest)
+            return call
+
+        for attr in ("_decode", "_decode_paged"):
+            setattr(eng, attr, spy("decode", getattr(eng, attr)))
+        for attr in ("_prefill_chunk", "_prefill_chunk_paged"):
+            if getattr(eng, attr) is not None:
+                setattr(eng, attr, spy("prefill", getattr(eng, attr)))
+        trace.enable()
+        self._serve(eng, cfg, n=3)
+        spans = trace.spans(float("-inf"), float("inf"))
+        dec = [s.args for s in spans if s.name == "serve.decode_step"]
+        pre = [s.args for s in spans if s.name == "serve.prefill_batch"]
+        assert len(dec) == len(calls["decode"]) > 0
+        assert len(pre) == len(calls["prefill"]) > 0
+        for a, (shape, _) in zip(dec, calls["decode"]):
+            assert a["padded_rows"] == shape[0]
+            assert 0 < a["rows"] <= a["padded_rows"]
+        for a, (shape, lens) in zip(pre, calls["prefill"]):
+            assert a["padded_rows"] == shape[0]
+            assert a["padded_tokens"] == shape[0] * shape[1]
+            real = np.asarray(lens)[:a["rows"]]
+            assert a["tokens"] == int(real.sum())
+        # every request prefills once and decodes a row per later token
+        assert sum(a["rows"] for a in pre) == 3
+        assert sum(a["rows"] for a in dec) == sum(
+            len(r.out_tokens) - 1 for r in eng.finished)
+        assert sum(s.args["finished"] for s in spans
+                   if s.name == "serve.emit") == 3
+
+    def test_one_queue_wait_per_admission(self, smollm):
+        cfg, model, params = smollm
+        eng = _engine(model, params, block_size=2, num_blocks=9,
+                      max_running=3)
+        trace.enable()
+        for i in range(4):
+            eng.submit(_prompt(cfg, 4, seed=i), 6)
+        while eng.has_work():
+            eng.step()
+        waits = [s for s in trace.spans(float("-inf"), float("inf"))
+                 if s.name == "serve.queue_wait"]
+        hist = eng.registry.get("serve_queue_wait_seconds")
+        assert eng.metrics()["preemptions"] >= 1      # re-admissions too
+        assert len(waits) == hist.count == \
+            eng.registry.get("serve_requests_admitted_total").value
+        assert sum(s.end - s.start for s in waits) == pytest.approx(
+            hist.sum, rel=1e-9, abs=1e-12)
+        assert {s.args["req_id"] for s in waits} == {0, 1, 2, 3}
+
+    def test_jitted_programs_carry_names(self, smollm, caplog):
+        cfg, model, params = smollm
+        eng = _engine(model, params)
+        names = {"_prefill": "serve_prefill", "_decode": "serve_decode",
+                 "_decode_paged": "serve_decode_paged",
+                 "_prefill_chunk": "serve_prefill_chunk",
+                 "_prefill_chunk_paged": "serve_prefill_paged",
+                 "_sample": "serve_sample"}
+        for attr, name in names.items():
+            fn = getattr(eng, attr)
+            assert fn is None or fn.__name__ == name, attr
+        # the modules that compile while serving carry those names
+        jax.config.update("jax_log_compiles", True)
+        try:
+            with caplog.at_level("WARNING", logger="jax"):
+                self._serve(eng, cfg, n=2)
+        finally:
+            jax.config.update("jax_log_compiles", False)
+        compiled = " ".join(r.getMessage() for r in caplog.records)
+        for name in ("serve_sample", "serve_decode_paged" if
+                     eng.paged_kernel else "serve_decode"):
+            assert f"jit({name})" in compiled, name
+        from repro.core.tsqr import RStreamer
+        rs = RStreamer(4)
+        x = jnp.ones((8, 4), jnp.float32)
+        assert "@jit_calib_fold_first" in rs._first.lower(x).as_text()
+        assert "@jit_calib_fold " in rs._update.lower(
+            jnp.eye(4), x).as_text()
+
+    def test_calibration_spans_nest(self, smollm):
+        from repro.core.calibrate import Calibrator
+        cfg, model, params = smollm
+        cal = Calibrator(max_tokens_per_record=8)
+        trace.enable()
+        tokens = jnp.asarray(np.stack([_prompt(cfg, 12, seed=s)
+                                       for s in range(2)]))
+        model.capture_forward(params, {"tokens": tokens}, cal)
+        spans = trace.spans(float("-inf"), float("inf"))
+        by_id = {s.id: s for s in spans}
+        caps = [s for s in spans if s.name == "calib.capture"]
+        recs = [s for s in spans if s.name == "calib.record"]
+        folds = [s for s in spans if s.name == "calib.fold"]
+        assert len(caps) == 1 and len(recs) == len(cal.streams)
+        assert all(by_id[r.parent].name == "calib.capture" for r in recs)
+        assert all(by_id[f.parent].name == "calib.record" for f in folds)
+        # 24 rows a record, folded 8 at a time
+        assert len(folds) == 3 * len(recs)
+        assert {(f.args["rows"], f.args["n"]) for f in folds} >= {(8, cfg.d_model)}
 
 
 # ---------------------------------------------------------------------------
