@@ -6,13 +6,21 @@ tens of GB, so we never materialize it. Each target linear layer owns an
 via TSQR ([R; chunkᵀ] → QR). The Gram accumulator (for the SVD-LLM baselines)
 streams the same way via the Pallas ``gram_accum`` kernel.
 
+Linears that read one activation (q/k/v, gate/up, cross-attention k/v)
+fold the same rows into the same starting state, which gives the same R
+bit for bit. The calibrator remembers its last fold and hands its result
+to the next record of that very array from that very state, so each
+shared input is folded once per batch while every path keeps its own
+stream.
+
 On a mesh, the per-shard R factors combine with the butterfly
 ``distributed_tsqr_r`` (see core/tsqr.py) — calibration activations are
 born sharded over the data axis and the tree never gathers them.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Optional
+import math
+from typing import Any, Dict, Iterable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +30,16 @@ from repro.core.tsqr import RStreamer, square_r
 from repro.kernels import ops as kops
 from repro.models.linear import CaptureDict
 from repro.obs import trace
+
+
+class _Fold(NamedTuple):
+    """The calibrator's last fold: the input array (held, so that ``is``
+    cannot match a new array at a reused address), the stream state it
+    started from and the state it produced, and its Gram."""
+    x: Any
+    before: Tuple[Optional[jax.Array], int]
+    after: Tuple[Optional[jax.Array], int]
+    gram: Optional[jax.Array]
 
 
 class Calibrator:
@@ -34,6 +52,7 @@ class Calibrator:
         self.collect_gram = collect_gram
         self.dtype = dtype
         self.max_tokens = max_tokens_per_record
+        self._last: Optional[_Fold] = None
 
     # ------------------------------------------------------------ capture
     def wrap(self, block_params, path: str):
@@ -60,15 +79,28 @@ class Calibrator:
 
     def record(self, path: str, x: jax.Array):
         n = x.shape[-1]
-        flat = jnp.asarray(x, self.dtype).reshape(-1, n)
-        with trace.span("calib.record", path=path, tokens=flat.shape[0]):
-            if path not in self.streams:
-                self.streams[path] = RStreamer(n, self.dtype)
-            # fold in manageable chunks (bounds the QR stack size)
-            for i in range(0, flat.shape[0], self.max_tokens):
-                self.streams[path].update(flat[i:i + self.max_tokens])
-            if self.collect_gram:
-                g = kops.gram_accum(flat)
+        if path not in self.streams:
+            self.streams[path] = RStreamer(n, self.dtype)
+        stream = self.streams[path]
+        before = stream.state
+        last = self._last
+        shared = (last is not None and last.x is x
+                  and before[0] is last.before[0]
+                  and before[1] == last.before[1])
+        with trace.span("calib.record", path=path,
+                        tokens=math.prod(x.shape[:-1]), shared=shared):
+            if shared:
+                # the same rows folded into the same state: the same bits
+                stream.state = last.after
+                g = last.gram
+            else:
+                flat = jnp.asarray(x, self.dtype).reshape(-1, n)
+                # fold in manageable chunks (bounds the QR stack size)
+                for i in range(0, flat.shape[0], self.max_tokens):
+                    stream.update(flat[i:i + self.max_tokens])
+                g = kops.gram_accum(flat) if self.collect_gram else None
+                self._last = _Fold(x, before, stream.state, g)
+            if g is not None:
                 self.grams[path] = g if path not in self.grams \
                     else self.grams[path] + g
 
@@ -78,6 +110,7 @@ class Calibrator:
         starts its next window on the same instance."""
         self.streams.clear()
         self.grams.clear()
+        self._last = None
 
     # ------------------------------------------------------------ results
     def r_factors(self) -> Dict[str, jax.Array]:

@@ -21,7 +21,7 @@ R is unique and comparable across strategies in tests.
 from __future__ import annotations
 
 import math
-from typing import Iterable, Optional, Sequence
+from typing import Iterable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +104,15 @@ class RStreamer:
         with trace.span("calib.fold", rows=rows, n=self.n):
             self._r = (self._first(chunk) if self._r is None
                        else self._update(self._r, chunk))
+
+    @property
+    def state(self) -> Tuple[Optional[jax.Array], int]:
+        """``(R or None, tokens_seen)``: what the next fold starts from."""
+        return self._r, self.tokens_seen
+
+    @state.setter
+    def state(self, state: Tuple[Optional[jax.Array], int]) -> None:
+        self._r, self.tokens_seen = state
 
     @property
     def r(self) -> jax.Array:

@@ -691,8 +691,12 @@ class TestEngineSpans:
         assert len(caps) == 1 and len(recs) == len(cal.streams)
         assert all(by_id[r.parent].name == "calib.capture" for r in recs)
         assert all(by_id[f.parent].name == "calib.record" for f in folds)
-        # 24 rows a record, folded 8 at a time
-        assert len(folds) == 3 * len(recs)
+        # 24 rows a record, folded 8 at a time; a record of the input the
+        # record before it folded (wk, wv, up) takes that fold's R
+        folded = {r.id for r in recs if not r.args["shared"]}
+        assert len(folded) == 4 * cfg.n_layers
+        assert len(folds) == 3 * len(folded)
+        assert all(f.parent in folded for f in folds)
         assert {(f.args["rows"], f.args["n"]) for f in folds} >= {(8, cfg.d_model)}
 
 
