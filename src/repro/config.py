@@ -17,10 +17,28 @@ class MoEConfig:
     top_k: int = 0
     num_shared: int = 0               # always-on shared experts
     d_ff_expert: int = 0              # per-expert hidden dim
-    capacity_factor: float = 1.25
-    min_capacity: int = 4
+    norm_topk_prob: bool = True       # renormalize the top-k weights to sum 1
+    # the experts this layer holds, [e_start, e_start + e_count): one
+    # expert-parallel share (0 = all); routing still runs over every expert
+    e_start: int = 0
+    e_count: int = 0
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+
+    @property
+    def held(self) -> int:
+        return self.e_count or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (HF ``rope_scaling`` of type ``yarn``)."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,6 +70,7 @@ class ModelConfig:
     vocab_size: int = 256
     max_seq_len: int = 8192
     rope_theta: float = 10000.0
+    yarn: Optional[YarnConfig] = None  # None = plain rotary embedding
     norm_eps: float = 1e-5
 
     # --- family-specific knobs -------------------------------------------
@@ -74,7 +93,7 @@ class ModelConfig:
     scale_depth: float = 0.0          # 0 = off; else residual scaled by scale_depth/sqrt(L)
     dim_model_base: int = 0           # 0 = off; logits scaled by d_model/dim_model_base
 
-    # MLA (deepseek-v2)
+    # MLA (deepseek-v2): the latent always passes an RMSNorm
     kv_lora_rank: int = 0             # 0 = plain GQA
     qk_rope_dim: int = 0
     qk_nope_dim: int = 0

@@ -17,5 +17,4 @@ CONFIG = ModelConfig(
 SMOKE = dataclasses.replace(
     CONFIG, n_layers=3, d_model=64, n_heads=4, n_kv_heads=4, d_ff=128,
     vocab_size=256, max_seq_len=256,
-    moe=MoEConfig(num_experts=8, top_k=2, num_shared=1, d_ff_expert=32,
-                  min_capacity=2))
+    moe=MoEConfig(num_experts=8, top_k=2, num_shared=1, d_ff_expert=32))

@@ -21,6 +21,5 @@ CONFIG = ModelConfig(
 SMOKE = dataclasses.replace(
     CONFIG, n_layers=8, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
     vocab_size=256, max_seq_len=256, attn_every=4, attn_offset=2,
-    moe=MoEConfig(num_experts=4, top_k=2, num_shared=0, d_ff_expert=32,
-                  min_capacity=2),
+    moe=MoEConfig(num_experts=4, top_k=2, num_shared=0, d_ff_expert=32),
     mamba=MambaConfig(d_state=8, d_conv=4, expand=2, dt_rank=16))

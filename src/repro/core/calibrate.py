@@ -13,6 +13,13 @@ to the next record of that very array from that very state, so each
 shared input is folded once per batch while every path keeps its own
 stream.
 
+A MoE layer's routed experts arrive together (``record_experts``): each
+held expert's rows, zero-padded to one chunk size, stacked over the
+experts. One jitted program folds the whole stack, vmapped over the
+experts, each from its own R (a zero R for a stream with no rows yet), so
+the fold compiles once per chunk size and width, whatever the counts.
+Each expert keeps its own stream and true ``tokens_seen``.
+
 On a mesh, the per-shard R factors combine with the butterfly
 ``distributed_tsqr_r`` (see core/tsqr.py) — calibration activations are
 born sharded over the data axis and the tree never gathers them.
@@ -20,13 +27,14 @@ born sharded over the data axis and the tree never gathers them.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Iterable, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.precision import highest_precision
-from repro.core.tsqr import RStreamer, square_r
+from repro.core.tsqr import RStreamer, calib_fold_group, square_r
 from repro.kernels import ops as kops
 from repro.models.linear import CaptureDict
 from repro.obs import trace
@@ -53,6 +61,8 @@ class Calibrator:
         self.dtype = dtype
         self.max_tokens = max_tokens_per_record
         self._last: Optional[_Fold] = None
+        self._fold_group = jax.jit(calib_fold_group)
+        self._zero_r: Dict[int, jax.Array] = {}
 
     # ------------------------------------------------------------ capture
     def wrap(self, block_params, path: str):
@@ -103,6 +113,40 @@ class Calibrator:
             if g is not None:
                 self.grams[path] = g if path not in self.grams \
                     else self.grams[path] + g
+
+    def record_experts(self, path: str, e_start: int,
+                       stacks: List[Tuple[jax.Array, jax.Array]],
+                       counts: np.ndarray) -> None:
+        """A MoE layer's held experts ``e_start + e``: per chunk, the input
+        (E, rows, d) and hidden (E, rows, f) stacks, expert ``e``'s
+        ``counts[e]`` routed rows first in its slots and zeros after them.
+        Each (chunk, tap) is one grouped fold; an expert whose chunk holds
+        no rows keeps its R as it is."""
+        counts = np.asarray(counts)
+        for j, pair in enumerate(stacks):
+            for tap, stack in zip(("in", "hid"), pair):
+                e_count, rows, n = stack.shape
+                got = np.clip(counts - j * rows, 0, rows)
+                names = [f"{path}/expert{e_start + e}/{tap}"
+                         for e in range(e_count)]
+                for name, k in zip(names, got):
+                    if k and name not in self.streams:
+                        self.streams[name] = RStreamer(n, self.dtype)
+                live = [self.streams.get(name) for name in names]
+                if n not in self._zero_r:
+                    self._zero_r[n] = jnp.zeros((n, n), self.dtype)
+                zero = self._zero_r[n]
+                rs = tuple(zero if s is None or s.state[0] is None
+                           else s.state[0] for s in live)
+                with trace.span("calib.expert_fold", path=f"{path}/{tap}",
+                                experts=e_count, rows=int(got.sum()),
+                                padded_rows=e_count * rows) as sp:
+                    new = self._fold_group(rs, stack.astype(self.dtype))
+                    for s, r, k in zip(live, new, got):
+                        if k:
+                            s.state = (r, s.tokens_seen + int(k))
+                    sp.set(underfilled=sum(
+                        s is not None and s.tokens_seen < n for s in live))
 
     def reset(self) -> None:
         """Drop every accumulated stream and Gram, keeping the capture

@@ -28,7 +28,7 @@ from repro.models.linear import rank_for_ratio
 # projections; embeddings / lm_head / routers / norms / recurrence params stay)
 COMPRESSIBLE_KEYS = {"wq", "wk", "wv", "wo", "up", "gate", "down",
                      "in_proj", "out_proj", "ff_up", "ff_down",
-                     "w_dkv", "shared"}
+                     "wkv_a", "wkv_b", "shared"}
 MIN_DIM = 32
 
 
@@ -91,10 +91,11 @@ def _solve(w_mat, r_factor, rank, ccfg: CompressConfig):
 
 
 def compress_params(params, r_factors: Dict[str, jax.Array],
-                    ccfg: CompressConfig, rank_map=None):
+                    ccfg: CompressConfig, rank_map=None, e_start: int = 0):
     """Returns (new_params, [LayerReport...]). ``r_factors`` keys are the
     calibrator paths ('blocks/3/sub0/mixer/wq', ...). ``rank_map`` (adaptive
-    allocation, core/rank_alloc.py) overrides the uniform ratio per path."""
+    allocation, core/rank_alloc.py) overrides the uniform ratio per path.
+    An expert bank's row ``e`` is expert ``e_start + e`` (the share held)."""
     reports = []
 
     def _compress_experts(node, path):
@@ -113,7 +114,7 @@ def compress_params(params, r_factors: Dict[str, jax.Array],
             bts, ats = [], []
             compressed_any = False
             for e in range(e_total):
-                rf = r_factors.get(f"{p}/expert{e}/{rf_kind}")
+                rf = r_factors.get(f"{p}/expert{e_start + e}/{rf_kind}")
                 w = w_stack[e]
                 d_in, d_out = w.shape
                 rank = (ccfg.rank if ccfg.rank > 0
@@ -260,10 +261,11 @@ def compress_model(model, params, calibrator, ccfg: CompressConfig, *,
 
     reports = []
     new_params = dict(params)
+    e_start = model.cfg.moe.e_start
     # non-stacked portions (prefix layers, top-level)
     np_, rep_ = compress_params(
         {k: v for k, v in params.items() if k not in stacked_keys},
-        flat_rf, ccfg, rank_map=rank_map)
+        flat_rf, ccfg, rank_map=rank_map, e_start=e_start)
     new_params.update(np_)
     reports.extend(rep_)
 
@@ -281,7 +283,7 @@ def compress_model(model, params, calibrator, ccfg: CompressConfig, *,
                 sub_map = {p[len(pre):]: v for p, v in rank_map.items()
                            if p.startswith(pre)}
             nb, rp = compress_params(blk, blk_rf.get(r, {}), ccfg,
-                                     rank_map=sub_map)
+                                     rank_map=sub_map, e_start=e_start)
             for item in rp:
                 item.path = f"{skey}/{r}/" + item.path
             reports.extend(rp)

@@ -73,8 +73,9 @@ def tsqr_tree(chunks: Sequence[jax.Array]) -> jax.Array:
 
 
 def calib_fold_first(chunk: jax.Array) -> jax.Array:
-    """R of a stream's first chunk (jitted, the program ``calib_fold_first``)."""
-    return qr_r(chunk)
+    """R of a stream's first chunk, square (jitted, the program
+    ``calib_fold_first``)."""
+    return square_r(qr_r(chunk))
 
 
 def calib_fold(r: jax.Array, chunk: jax.Array) -> jax.Array:
@@ -82,11 +83,23 @@ def calib_fold(r: jax.Array, chunk: jax.Array) -> jax.Array:
     return stack_qr(r, chunk)
 
 
+def calib_fold_group(rs: Tuple[jax.Array, ...], chunks: jax.Array
+                     ) -> Tuple[jax.Array, ...]:
+    """Fold each of E chunks into its own stream's R: ``rs`` E square
+    (n, n) R factors (zero for a stream with no rows yet: zero rows leave
+    RᵀR, and so R, unchanged), ``chunks`` (E, rows, n) zero-padded. Jitted
+    per (E, rows, n), the program ``calib_fold_group``: one program folds
+    every expert a MoE layer holds."""
+    return tuple(jax.vmap(stack_qr)(jnp.stack(rs), chunks))
+
+
 class RStreamer:
     """Stateful streaming R accumulator used by the calibration pipeline.
 
     Never materializes X: ``update`` consumes a (tokens, n) activation chunk,
-    ``finish`` returns the final R (optionally μ-augmented, Prop. 3).
+    ``finish`` returns the final R (optionally μ-augmented, Prop. 3). R is
+    kept square, zero-padded where the first chunk has fewer rows than
+    width, so every later fold of a stream is one program.
     """
 
     def __init__(self, n: int, dtype=jnp.float32):

@@ -37,10 +37,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Iterable, Tuple
+from typing import Dict, Iterable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.calibrate import Calibrator
@@ -115,7 +116,9 @@ class _ShardCalibrator(Calibrator):
         self.stacks: Dict[str, jax.Array] = {}
         self.tokens: Dict[str, int] = {}
 
-    def record(self, path: str, x: jax.Array):
+    def record(self, path: str, x: jax.Array, tokens: Optional[int] = None):
+        """Fold ``x``'s rows; ``tokens`` counts the true ones where zero
+        rows pad them (a routed expert's slots)."""
         n = x.shape[-1]
         flat = jnp.asarray(x, self.dtype).reshape(-1, n)
         k = flat.shape[0]
@@ -125,6 +128,7 @@ class _ShardCalibrator(Calibrator):
             # zero rows leave RᵀR, and so R, unchanged
             flat = jnp.concatenate([flat, jnp.zeros((pad, n), flat.dtype)])
         flat = jax.device_put(flat, self.rows)
+        k = k if tokens is None else tokens
         with trace.span("calib.record", path=path, tokens=k):
             prev = self.stacks.get(path)
             fold = _fold_fn(self.mesh, self.axis, self.max_tokens,
@@ -132,6 +136,17 @@ class _ShardCalibrator(Calibrator):
             self.stacks[path] = fold(flat) if prev is None \
                 else fold(flat, prev)
             self.tokens[path] = self.tokens.get(path, 0) + k
+
+    def record_experts(self, path, e_start, stacks, counts):
+        """Each held expert's padded slots, folded per shard like any
+        record (its zero rows change nothing), with its true count."""
+        for j, pair in enumerate(stacks):
+            for tap, stack in zip(("in", "hid"), pair):
+                rows = stack.shape[1]
+                got = np.clip(np.asarray(counts) - j * rows, 0, rows)
+                for e in np.flatnonzero(got):
+                    self.record(f"{path}/expert{e_start + e}/{tap}",
+                                stack[e], tokens=int(got[e]))
 
 
 @dataclasses.dataclass

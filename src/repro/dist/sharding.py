@@ -47,8 +47,7 @@ _COL_KEYS = frozenset({
     "wq", "wk", "wv",                 # GQA / MLA / cross-attention queries
     "gate", "up", "ff_up",            # GLU MLP + sLSTM feed-forward
     "in_proj",                        # mamba input projection
-    "w_dkv", "w_krope",               # MLA latent down-projections
-    "w_uk", "w_uv",                   # MLA latent up-projections (raw arrays)
+    "wkv_a", "wkv_b",                 # MLA latent down / up projections
     "x_proj", "dt_proj",              # mamba SSM parameter projections
 })
 _ROW_KEYS = frozenset({

@@ -12,12 +12,15 @@ KV caches are explicit pytrees; decode writes one position via
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ParallelCtx, apply_rope, softcap, dense_init, split_key
+from repro.models.common import (ParallelCtx, apply_rope, dense_init,
+                                 rmsnorm, rmsnorm_init, softcap, split_key,
+                                 yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -41,14 +44,13 @@ def mla_init(key, cfg, dtype=jnp.float32):
     d = cfg.d_model
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     kl = cfg.kv_lora_rank
-    ks = split_key(key, 6)
+    ks = split_key(key, 4)
     return {
         "wq": {"w": dense_init(ks[0], d, h * (dn + dr), dtype)},
-        "w_dkv": {"w": dense_init(ks[1], d, kl, dtype)},
-        "w_krope": {"w": dense_init(ks[2], d, dr, dtype)},
-        "w_uk": dense_init(ks[3], kl, h * dn, dtype),     # raw: used via einsum
-        "w_uv": dense_init(ks[4], kl, h * dv, dtype),
-        "wo": {"w": dense_init(ks[5], h * dv, d, dtype)},
+        "wkv_a": {"w": dense_init(ks[1], d, kl + dr, dtype)},
+        "kv_norm": rmsnorm_init(kl),
+        "wkv_b": {"w": dense_init(ks[2], kl, h * (dn + dv), dtype)},
+        "wo": {"w": dense_init(ks[3], h * dv, d, dtype)},
     }
 
 
@@ -115,9 +117,15 @@ def _dense_sdpa(q, k, v, *, q_offset, causal, window, cap, scale):
     return o.reshape(b, tq, hq, hd)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "window", "cap", "scale", "chunk_q", "chunk_kv",
+    "skip_masked_blocks"))
 def _chunked_sdpa(q, k, v, *, q_offset, causal, window, cap, scale,
                   chunk_q: int, chunk_kv: int, skip_masked_blocks: bool = False):
-    """FlashAttention-style two-level scan; O(chunk² ) score memory."""
+    """FlashAttention-style two-level scan; O(chunk² ) score memory.
+    Jitted, so that an eager caller (calibration's capture forward) runs
+    one compiled program per shape instead of re-tracing its scans, whose
+    bodies close over the call's arrays, at every call."""
     b, tq, hq, hd = q.shape
     tk, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
@@ -305,23 +313,51 @@ def mla_empty_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
             "k_rope": jnp.zeros((batch, max_len, cfg.qk_rope_dim), dtype)}
 
 
+def mla_scale(cfg) -> float:
+    """192^-0.5 at DeepSeek-V2's widths, times YaRN's mscale(mscale_all_dim)
+    squared where the rope is YaRN-scaled."""
+    scale = 1.0 / ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** 0.5)
+    if cfg.yarn is not None and cfg.yarn.mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _deinterleave(x):
+    """HF DeepSeek-V2 reads a rope part's pairs (0, 1), (2, 3), ... as the
+    two halves that ``rotate_half`` rotates: evens first, then odds."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
 def mla_apply(cfg, params, x, *, ctx: ParallelCtx, cos_sin=None,
               cache=None, pos=None, **_) -> Tuple[jax.Array, Optional[dict]]:
-    from repro.models.linear import linear_apply
+    """Multi-head latent attention (DeepSeek-V2, no q-LoRA):
+
+        [q_nope, q_pe] = x W_q                 per head, dn + dr
+        [c, k_pe]      = x W_kv_a              kv_lora_rank + dr (k_pe shared)
+        c              = RMSNorm(c)
+        [k_nope, v]    = c W_kv_b              per head, dn + dv
+        q_pe, k_pe     = rope(q_pe), rope(k_pe)     de-interleaved, YaRN
+        o = softmax_causal((q_nope.k_nope + q_pe.k_pe) * mla_scale) v
+        y = o W_o
+
+    The cache holds c and the rotated k_pe. Decode scores in latent space
+    (the absorbed form): q_nope W_uk against c and the context c W_uv, with
+    W_uk, W_uv the nope and value columns of the same W_kv_b."""
+    from repro.models.linear import linear_apply, linear_weight_matrix
     b, t, _ = x.shape
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    scale = 1.0 / ((dn + dr) ** 0.5)
+    kl = cfg.kv_lora_rank
+    scale = mla_scale(cfg)
     q = linear_apply(params["wq"], x).reshape(b, t, h, dn + dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    c = linear_apply(params["w_dkv"], x)                       # (b, t, kl)
-    k_rope = linear_apply(params["w_krope"], x)[:, :, None, :]  # (b, t, 1, dr)
+    q_nope, q_rope = q[..., :dn], _deinterleave(q[..., dn:])
+    kv = linear_apply(params["wkv_a"], x)                      # (b, t, kl+dr)
+    c = rmsnorm(params["kv_norm"], kv[..., :kl], cfg.norm_eps)
+    k_rope = _deinterleave(kv[..., kl:])[:, :, None, :]        # (b, t, 1, dr)
     if cos_sin is not None:
         cos, sin = cos_sin
         q_rope = apply_rope(q_rope, cos, sin)
         k_rope = apply_rope(k_rope, cos, sin)
     k_rope = k_rope[:, :, 0, :]
-    w_uk = params["w_uk"].astype(x.dtype).reshape(cfg.kv_lora_rank, h, dn)
-    w_uv = params["w_uv"].astype(x.dtype).reshape(cfg.kv_lora_rank, h, dv)
 
     if cache is not None and pos is not None:
         # absorbed decode: score in latent space, never materialize per-head K/V
@@ -336,6 +372,9 @@ def mla_apply(cfg, params, x, *, ctx: ParallelCtx, cos_sin=None,
                 cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
                 (0, pos, 0))
         new_cache = {"c": cf, "k_rope": rf}
+        w_kvb = linear_weight_matrix(params["wkv_b"]).T.astype(x.dtype)
+        w_kvb = w_kvb.reshape(kl, h, dn + dv)
+        w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
         q_c = jnp.einsum("bthd,khd->bthk", q_nope, w_uk)       # (b,1,h,kl)
         s = (jnp.einsum("bthk,bsk->bhts", q_c, cf.astype(x.dtype)) +
              jnp.einsum("bthd,bsd->bhts", q_rope, rf.astype(x.dtype)))
@@ -349,8 +388,8 @@ def mla_apply(cfg, params, x, *, ctx: ParallelCtx, cos_sin=None,
         o = jnp.einsum("bthk,khd->bthd", ctx_c, w_uv)          # (b,t,h,dv)
     else:
         # train/prefill: expand K/V (MHA after expansion)
-        k_nope = jnp.einsum("btk,khd->bthd", c, w_uk)
-        v = jnp.einsum("btk,khd->bthd", c, w_uv)
+        kvb = linear_apply(params["wkv_b"], c).reshape(b, t, h, dn + dv)
+        k_nope, v = kvb[..., :dn], kvb[..., dn:]
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, t, h, dr))], -1)
         qq = jnp.concatenate([q_nope, q_rope], -1)

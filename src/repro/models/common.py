@@ -2,10 +2,12 @@
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -22,7 +24,6 @@ class ParallelCtx:
     attn_chunk_q: int = 2048
     attn_chunk_kv: int = 1024
     causal_pair_scan: bool = False                  # §Perf: skip masked kv blocks
-    moe_capacity_factor: Optional[float] = None     # override cfg capacity
     use_pallas: bool = False                        # TPU flash-attention kernel
     mlstm_chunkwise: bool = False                   # chunkwise-parallel mLSTM
     paged_attn_impl: Optional[str] = None           # paged decode kernel: None/
@@ -108,16 +109,57 @@ def softcap(x, cap: float):
 # RoPE (standard + M-RoPE)
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float, dtype=jnp.float32):
+def _host_freqs(head_dim: int, theta: float) -> np.ndarray:
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=dtype) / half))
+    return 1.0 / (theta ** (np.arange(half) / half))
 
 
-def rope_cos_sin(positions, head_dim: int, theta: float):
-    """positions: (..., T) int -> cos/sin (..., T, head_dim//2)."""
-    inv = rope_freqs(head_dim, theta)
-    ang = positions[..., None].astype(jnp.float32) * inv
-    return jnp.cos(ang), jnp.sin(ang)
+def rope_freqs(head_dim: int, theta: float, dtype=jnp.float32):
+    """Inverse frequencies theta^(-2i/d), computed on the host in float64
+    and rounded once: a device's float32 ``pow`` is off by ~1e-6 of the
+    value, which at position 2047 turns the angle by ~1e-3 rad."""
+    return jnp.asarray(_host_freqs(head_dim, theta), dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, yarn):
+    """YaRN inverse frequencies (HF ``DeepseekV2YarnRotaryEmbedding``): the
+    plain ones at the fast dims, divided by ``factor`` at the slow dims,
+    blended by a linear ramp between the correction dims; on the host in
+    float64, as ``rope_freqs``."""
+    half = head_dim // 2
+
+    def corr_dim(rot):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    extra = _host_freqs(head_dim, theta)
+    return jnp.asarray(extra / yarn.factor * ramp + extra * (1.0 - ramp),
+                       jnp.float32)
+
+
+def rope_cos_sin(positions, head_dim: int, theta: float, yarn=None):
+    """positions: (..., T) int -> cos/sin (..., T, head_dim//2). With
+    ``yarn`` the frequencies are YaRN's and cos/sin carry its
+    mscale / mscale_all_dim factor."""
+    if yarn is None:
+        ang = positions[..., None].astype(jnp.float32) * \
+            rope_freqs(head_dim, theta)
+        return jnp.cos(ang), jnp.sin(ang)
+    ang = positions[..., None].astype(jnp.float32) * \
+        yarn_freqs(head_dim, theta, yarn)
+    scale = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
 def apply_rope(x, cos, sin):

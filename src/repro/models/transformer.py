@@ -278,7 +278,7 @@ class LM:
             return mrope_cos_sin(pids, cfg.head_dim, cfg.rope_theta,
                                  cfg.mrope_sections)
         hd = cfg.qk_rope_dim if cfg.kv_lora_rank else cfg.head_dim
-        return rope_cos_sin(pos, hd, cfg.rope_theta)
+        return rope_cos_sin(pos, hd, cfg.rope_theta, cfg.yarn)
 
     # ---------------- backbone ----------------------------------------------
     def _backbone(self, params, x, *, ctx: ParallelCtx, cache=None, pos=None,

@@ -58,13 +58,11 @@ class TestMoEShardMap:
                               shard_map_moe=True)
             y_shd, aux_shd = jax.jit(
                 lambda p, x: ffn_lib.moe_apply(cfg, p, x, ctx=ctx))(params, x)
-            # same routing math; capacity differs (per-shard), so compare
-            # loosely on values and tightly on shapes/finite-ness
+            # same routing math, and dispatch is dropless on both sides:
+            # each shard's experts see every row routed to them
             assert y_shd.shape == y_loc.shape
-            assert np.all(np.isfinite(np.asarray(y_shd)))
-            diff = np.abs(np.asarray(y_shd) - np.asarray(y_loc)).max()
-            scale = np.abs(np.asarray(y_loc)).max()
-            assert diff < 0.3 * scale, (diff, scale)
+            np.testing.assert_allclose(np.asarray(y_shd), np.asarray(y_loc),
+                                       rtol=2e-3, atol=2e-3)
             print("OK")
         """)
 
@@ -79,10 +77,9 @@ class TestMoEShardMap:
                                  axis_types=(jax.sharding.AxisType.Auto,)*2)
             params = ffn_lib.moe_init(jax.random.PRNGKey(0), cfg)
             x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
-            ctx0 = ParallelCtx(moe_capacity_factor=64.0)
-            y_loc, _ = ffn_lib.moe_apply(cfg, params, x, ctx=ctx0)
+            y_loc, _ = ffn_lib.moe_apply(cfg, params, x, ctx=ParallelCtx())
             ctx = ParallelCtx(mesh=mesh, batch_axes=("data",),
-                              shard_map_moe=True, moe_capacity_factor=64.0)
+                              shard_map_moe=True)
             y_shd, _ = jax.jit(
                 lambda p, x: ffn_lib.moe_apply(cfg, p, x, ctx=ctx))(params, x)
             np.testing.assert_allclose(np.asarray(y_shd), np.asarray(y_loc),

@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every test worker
 imports this file.
 """
+import dataclasses
 import os
 
 import jax
@@ -24,6 +25,8 @@ from repro.core import coala
 from repro.kernels import chunked_prefill as cp
 from repro.kernels import gram_accum as ga
 from repro.kernels import paged_attention as pa
+from repro.models import ffn
+from repro.models.common import ParallelCtx
 
 CFG = get_config("smollm_135m")
 HQ, HKV, HD = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
@@ -108,3 +111,21 @@ def test_coala_solve_compiles(one_chip):
         jax.jit(coala.tsqr_lib.stack_qr).lower(
             _sds((n, n), jnp.float32, one_chip),
             _sds((8192, n), jnp.float32, one_chip)).compile()
+
+
+@pytest.mark.parametrize("e_count", [0, 8], ids=["all_64", "share_of_8"])
+def test_moe_grouped_matmul_compiles(one_chip, e_count):
+    """DeepSeek-V2-Lite's expert layer (d 2048, 64 experts of 1408, top-6,
+    2 shared) in bfloat16 over 1,024 tokens, holding every expert or one
+    chip's share of 8: the held experts run as the chip's grouped matmul
+    (``ragged_dot``), not as a masked dense product."""
+    cfg = get_config("deepseek_v2_lite_16b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, e_count=e_count))
+    shapes = jax.eval_shape(lambda k: ffn.moe_init(k, cfg, jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), shapes)
+    fn = jax.jit(lambda p, x: ffn.moe_apply(cfg, p, x, ctx=ParallelCtx()))
+    compiled = fn.lower(params, _sds((4, 256, cfg.d_model), jnp.bfloat16,
+                                     one_chip)).compile()
+    assert "ragged-dot" in compiled.as_text()
