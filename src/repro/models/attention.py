@@ -102,7 +102,13 @@ def _row_update(cache_arr, update, pos):
     )(cache_arr, update, pos)
 
 
+@functools.partial(jax.jit, static_argnames=("causal", "window", "cap",
+                                              "scale"))
 def _dense_sdpa(q, k, v, *, q_offset, causal, window, cap, scale):
+    """Scores, mask and softmax at full length; O(T²) score memory.
+    Jitted, so that an eager caller (calibration's capture forward) runs
+    one compiled program per shape, whose fusions make few passes over the
+    float32 scores, instead of one eager program per elementwise pass."""
     b, tq, hq, hd = q.shape
     tk, hkv = k.shape[1], k.shape[2]
     g = hq // hkv

@@ -100,6 +100,31 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("case", ["causal", "window", "softcap",
+                                      "row_offsets"])
+    def test_dense_path_jit_matches_eager_body(self, case):
+        """The jitted dense attention, called eagerly as the capture forward
+        calls it, computes what its op-by-op body computes."""
+        from repro.models.attention import _dense_sdpa
+        tq, tk = (4, 96) if case == "row_offsets" else (96, 96)
+        q = _rand((2, tq, 6, 32), 17)
+        k = _rand((2, tk, 2, 32), 18)
+        v = _rand((2, tk, 2, 32), 19)
+        kw = dict(q_offset=0, causal=True, window=0, cap=0.0,
+                  scale=32 ** -0.5)
+        if case == "window":
+            kw["window"] = 24
+        elif case == "softcap":
+            kw["cap"] = 2.0
+        elif case == "row_offsets":
+            kw["q_offset"] = jnp.asarray([17, 92], jnp.int32)
+        with jax.default_matmul_precision("highest"):
+            got = np.asarray(_dense_sdpa(q, k, v, **kw))
+            want = np.asarray(_dense_sdpa.__wrapped__(q, k, v, **kw))
+        assert np.isfinite(got).all()
+        rel = np.linalg.norm(got - want) / np.linalg.norm(want)
+        assert rel <= 1e-6, rel
+
 
 class TestModelPallasPath:
     @pytest.mark.slow
